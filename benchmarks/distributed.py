@@ -1,9 +1,10 @@
 """Distributed engine benchmark: replicate vs shuffle merge on a real
 multi-device host mesh (the paper's §1 centralise-vs-replicate trade).
 
-Runs in a subprocess with 8 forced host devices (the parent process has
-already locked jax to 1 device); reports per-strategy wall time and the
-collective schedule from the lowered HLO — the triclustering §Perf cell.
+A CPU rehearsal: runs in a subprocess on the CPU backend with 8 forced
+host devices (the parent process has already locked jax to its own
+devices); reports per-strategy wall time and the collective schedule
+from the lowered HLO.
 """
 from __future__ import annotations
 
@@ -39,20 +40,14 @@ for strategy in ("replicate", "shuffle"):
     r = dm(tuples); jax.block_until_ready(r.sig_lo)
     t0 = time.perf_counter(); r = dm(tuples); jax.block_until_ready(r.sig_lo)
     ms = (time.perf_counter() - t0) * 1e3
-    prof = None
-    try:
-        lowered = dm.lowered(tuples)
-        prof = profile_module(lowered.compile().as_text(), 8)
-    except Exception:
-        pass
+    prof = profile_module(dm.lowered(tuples).compile().as_text(), 8)
     out[strategy] = {"ms": ms,
                      "n_clusters": int(np.asarray(r.is_unique).sum()),
-                     "overflow": int(getattr(r, "overflow", 0))}
-    if prof is not None:
-        out[strategy]["collectives"] = {k: list(v)
-                                        for k, v in prof.by_kind.items()}
-        out[strategy]["coll_operand_bytes"] = prof.operand_bytes
-        out[strategy]["coll_wire_bytes"] = prof.wire_bytes
+                     "overflow": int(getattr(r, "overflow", 0)),
+                     "collectives": {k: list(v)
+                                     for k, v in prof.by_kind.items()},
+                     "coll_operand_bytes": prof.operand_bytes,
+                     "coll_wire_bytes": prof.wire_bytes}
 # NOAC (many-valued) through the same distributed pipeline
 vctx = synthetic.movielens_like(n_tuples=int(%(n)d), seed=0,
                                 values=True).deduplicated()
@@ -77,7 +72,9 @@ print("RESULT " + json.dumps(out))
 
 def run(n_tuples: int = 40_000):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ,
+    # the worker is a CPU rehearsal: it never reaches for a chip the
+    # parent may hold
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": os.path.join(root, "src")
            + os.pathsep + os.environ.get("PYTHONPATH", "")}
     proc = subprocess.run([sys.executable, "-c", _WORKER % {"n": n_tuples}],

@@ -109,6 +109,8 @@ def main(argv=None):
                     "should not overwrite the tracked full-scale file)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import distributed, packed, scaling, serving, table3, table4, \
         table5
     from .common import save_json

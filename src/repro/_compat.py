@@ -1,29 +1,19 @@
-"""jax API compatibility shims (single home; see DESIGN.md §6).
-
-jax 0.4.x lacks ``jax.shard_map``, ``jax.sharding.AxisType`` and returns
-``cost_analysis()`` as a one-dict-per-program list.  Everything in this
-repo goes through these wrappers instead of the moving jax surface.
-"""
+"""The two jax calls every mesh user shares, with the arguments this
+repository always passes (single home; see DESIGN.md §6)."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new jax; the experimental module on older
-    releases (0.4.x), where replication checking is ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without replication (vma) checking: the mining
+    bodies return per-shard blocks the checker cannot type."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, names):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, names,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(names))
-    return jax.make_mesh(shape, names)
+    """``jax.make_mesh`` with every axis of type Auto (GSPMD-propagated
+    shardings, as ``shard_map`` and the model layers expect)."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))

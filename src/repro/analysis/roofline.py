@@ -157,19 +157,10 @@ class RooflineReport:
                 f"{'' if self.fits else ' OVER'}")
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict across jax versions (0.4.x
-    returns a one-dict-per-program list)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def roofline_from_compiled(compiled, *, arch: str, shape, mesh_name: str,
                            n_devices: int, cfg, hw: HW = V5E,
                            hlo_text: Optional[str] = None) -> RooflineReport:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     txt = hlo_text if hlo_text is not None else compiled.as_text()
     prof = profile_module(txt, n_devices)

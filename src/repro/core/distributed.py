@@ -502,7 +502,7 @@ class DistributedMiner:
         start = shard_id * tl
         gen_l = sl(gen_of, start, tl)
         uniq_l = sl(is_unique, start, tl)
-        density = gen_l.astype(jnp.float32) / jnp.maximum(volume, 1.0)
+        density = PL.density_of(gen_l, volume)
         keep = uniq_l & (density >= jnp.float32(self.theta))
         if self.minsup:
             for c in cards:
@@ -718,7 +718,7 @@ class DistributedMiner:
         (or a non-fitting key) is the re-sort-every-shard baseline —
         the padded table through the one-shot ``__call__`` path."""
         if self._stores is None:
-            raise ValueError("no data ingested")
+            raise RS.NoDataError("no data ingested")
         self.snapshot_stream_version = self.stream_version
         incremental = (not full_remine
                        and all(s.incremental for s in self._stores))
@@ -766,7 +766,7 @@ class DistributedMiner:
         Signatures are bit-identical to :meth:`snapshot` / the batch
         miner (same hash vectors)."""
         if self._stores is None:
-            raise ValueError("no data ingested")
+            raise RS.NoDataError("no data ingested")
         self.snapshot_stream_version = self.stream_version
         incremental = (not full_remine
                        and all(s.incremental for s in self._stores))

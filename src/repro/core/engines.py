@@ -63,18 +63,27 @@ class MineRun:
     elapsed_s: float             # wall time of the first mining execution
                                  # (includes jit compile; excludes miner
                                  # construction and materialisation)
-    clusters: Optional[list]     # [(components, density), ...] or None
     result: Any                  # backend-native result object (or None)
     miner: Any                   # the engine instance (None for reference)
     rerun: Any = None            # zero-arg warm re-execution of the mining
                                  # step (no re-compile); returns the result
                                  # and records its time in ``rerun.last_s``
+    _n_tuples: int = 0
+    _clusters: Any = None        # list, or a zero-arg callable making it
 
     @property
     def tuples_per_s(self) -> float:
         return 0.0 if not self.elapsed_s else self._n_tuples / self.elapsed_s
 
-    _n_tuples: int = 0
+    @property
+    def clusters(self) -> Optional[list]:
+        """[(components, density), ...] of the kept clusters, or None
+        (distributed).  Materialised on first access: at published
+        scale every cluster's component sets take tens of GB of host
+        memory, far more than the mine itself."""
+        if callable(self._clusters):
+            self._clusters = self._clusters()
+        return self._clusters
 
 
 def mine(ctx: PolyadicContext, backend: str = "batch",
@@ -107,8 +116,9 @@ def mine(ctx: PolyadicContext, backend: str = "batch",
     total = time.perf_counter() - t0
     elapsed = getattr(rerun, "last_s", None) or total
     return MineRun(backend=backend, variant=variant, n_clusters=n_clusters,
-                   elapsed_s=elapsed, clusters=clusters, result=result,
-                   miner=miner, rerun=rerun, _n_tuples=ctx.num_tuples)
+                   elapsed_s=elapsed, result=result, miner=miner,
+                   rerun=rerun, _n_tuples=ctx.num_tuples,
+                   _clusters=clusters)
 
 
 def _noac_ctx(ctx: PolyadicContext) -> PolyadicContext:
@@ -122,7 +132,8 @@ def _noac_ctx(ctx: PolyadicContext) -> PolyadicContext:
 
 # ---------------------------------------------------------------------------
 # Engine runners.  Each returns (n_clusters, clusters, result, miner, rerun)
-# where ``rerun`` re-executes the mining step warm (no re-compile).
+# where ``clusters`` is the materialised list or a zero-arg callable that
+# makes it, and ``rerun`` re-executes the mining step warm (no re-compile).
 # ---------------------------------------------------------------------------
 
 def _pipe_kw(p):
@@ -167,14 +178,19 @@ def _batch_step(miner, p, tuples, values=None):
     return lambda: miner(tuples)
 
 
+def _lazy_clusters(miner, rerun):
+    """Run the mining step once; the engine-runner tuple of its result:
+    the kept count now, the component sets when first asked for."""
+    res = rerun()
+    return (int(np.asarray(res.keep).sum()),
+            lambda: miner.materialise(res), res, miner, rerun)
+
+
 @register_engine("batch", "prime")
 def _batch_prime(ctx, p):
     miner = BatchMiner(ctx.sizes, theta=p.get("theta", 0.0),
                        seed=p.get("seed", 0x5EED), **_pipe_kw(p))
-    rerun = _timed(_batch_step(miner, p, ctx.tuples))
-    res = rerun()
-    clusters = miner.materialise(res)
-    return len(clusters), clusters, res, miner, rerun
+    return _lazy_clusters(miner, _timed(_batch_step(miner, p, ctx.tuples)))
 
 
 @register_engine("batch", "noac")
@@ -184,10 +200,8 @@ def _batch_noac(ctx, p):
                       rho_min=p.get("rho_min", 0.0),
                       minsup=p.get("minsup", 0), seed=p.get("seed", 0x5EED),
                       **_pipe_kw(p))
-    rerun = _timed(_batch_step(miner, p, ctx.tuples, ctx.values))
-    res = rerun()
-    clusters = miner.materialise(res)
-    return len(clusters), clusters, res, miner, rerun
+    return _lazy_clusters(
+        miner, _timed(_batch_step(miner, p, ctx.tuples, ctx.values)))
 
 
 def _local_mesh():
@@ -252,10 +266,7 @@ def _run_streaming(ctx, p, values, **variant_kw):
                       values[lo:hi] if values is not None else None)
         return miner.snapshot()
 
-    rerun = _timed(ingest_and_snapshot)
-    res = rerun()
-    clusters = miner.materialise(res)
-    return len(clusters), clusters, res, miner, rerun
+    return _lazy_clusters(miner, _timed(ingest_and_snapshot))
 
 
 @register_engine("streaming", "prime")

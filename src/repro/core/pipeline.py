@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax version compatibility (canonical home: repro._compat)
+# the shared shard_map call (canonical home: repro._compat)
 from .._compat import shard_map  # noqa: F401  (re-export for the engines)
 from ..kernels import ops as kops
 from . import keys as K
@@ -443,6 +443,14 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
+def density_of(gen_count: jnp.ndarray, volume: jnp.ndarray) -> jnp.ndarray:
+    """Alg. 7 estimate ``gen_count / max(volume, 1)`` in float32 — the one
+    expression of every path, evaluated on the device: a TPU's f32
+    division is not IEEE-exact, so a host division differs in the last
+    bits."""
+    return gen_count.astype(jnp.float32) / jnp.maximum(volume, 1.0)
+
+
 def mine_tuples(tuples: jnp.ndarray, hash_lo: Sequence[jnp.ndarray],
                 hash_hi: Sequence[jnp.ndarray], *,
                 values: Optional[jnp.ndarray] = None,
@@ -518,7 +526,7 @@ def mine_tuples(tuples: jnp.ndarray, hash_lo: Sequence[jnp.ndarray],
                                      packed=s3_backend != "lexsort",
                                      sort_backend=s3_backend,
                                      use_pallas=use_pallas)
-    density = gen_of.astype(jnp.float32) / jnp.maximum(volume, 1.0)
+    density = density_of(gen_of, volume)
     keep = is_unique & (density >= jnp.float32(theta))
     if minsup:
         for c in comps:

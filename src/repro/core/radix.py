@@ -47,9 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: Digit width of the histogram (Pallas) formulation.
-HIST_DIGIT_BITS = 8
-HIST_BUCKETS = 1 << HIST_DIGIT_BITS
+# the histogram formulation's digit and the shared bit-field reader live
+# with its kernels, which import nothing from ``core``
+from ..kernels.radix_sort import HIST_DIGIT_BITS, extract_digit
 
 #: Valid values of the ``sort_backend`` selector threaded through the
 #: engines.  ``None``/'auto' resolve to 'radix' for fitting keys.
@@ -93,22 +93,6 @@ def plan_radix(live_bits: int, t: int,
         widths.append(min(w, live_bits - s))
         s += w
     return RadixPlan(int(t), live_bits, pb, tuple(shifts), tuple(widths))
-
-
-def extract_digit(words: Sequence[jnp.ndarray], shift: int,
-                  width: int) -> jnp.ndarray:
-    """Bits [shift, shift+width) of msb-first packed uint32 words, as a
-    uint32 digit.  ``width`` < 32 (a radix digit never spans a whole
-    word of the plan)."""
-    mask = jnp.uint32((1 << width) - 1)
-    if len(words) == 1:
-        return (words[0] >> shift) & mask
-    hi, lo = words
-    if shift >= 32:
-        return (hi >> (shift - 32)) & mask
-    if shift + width <= 32:
-        return (lo >> shift) & mask
-    return ((lo >> shift) | (hi << (32 - shift))) & mask
 
 
 # ---------------------------------------------------------------------------

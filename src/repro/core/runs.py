@@ -64,6 +64,10 @@ from . import keys as K
 from . import radix as RX
 
 
+class NoDataError(ValueError):
+    """A snapshot was asked of a stream that holds no rows yet."""
+
+
 @dataclasses.dataclass
 class Run:
     """One sorted run: per-mode sorted packed keys + log-row indices."""

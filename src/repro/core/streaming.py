@@ -160,7 +160,7 @@ class StreamingMiner(P.PipelineMiner):
         sorts) — the baseline the incremental path is verified and
         benchmarked against."""
         if self.state is None or self.state.count == 0:
-            raise ValueError("no data ingested")
+            raise RS.NoDataError("no data ingested")
         self.snapshot_stream_version = self.stream_version
         s = self._store()
         if full_remine or not s.incremental:

@@ -142,6 +142,13 @@ def _s3_fn(backend: str, use_pallas: bool):
     return _FN_CACHE[key]
 
 
+def _density_fn():
+    """Density window body: ``pipeline.density_of``."""
+    if "density" not in _FN_CACHE:
+        _FN_CACHE["density"] = jax.jit(P.density_of)
+    return _FN_CACHE["density"]
+
+
 # ---------------------------------------------------------------------------
 # Host helpers (numpy mirrors of the pipeline's segment primitives)
 # ---------------------------------------------------------------------------
@@ -458,8 +465,14 @@ def mine_windowed(rows, values, perms, *,
     is_unique = np.empty(t, bool)
     is_unique[order] = uniq_sorted
 
-    density = gen_count.astype(np.float32) / np.maximum(volume,
-                                                        np.float32(1.0))
+    # density per window on the device, where the monolithic path
+    # divides (its f32 division may round differently from the host's)
+    dfn = _density_fn()
+    density = np.empty(t, np.float32)
+    for w0, w1 in wplan.bounds:
+        density[w0:w1] = np.asarray(dfn(
+            jnp.asarray(_pad_tail(gen_count[w0:w1], budget, fill=0)),
+            jnp.asarray(_pad_tail(volume[w0:w1], budget, fill=0))))[:w1 - w0]
     keep = is_unique & (density >= np.float32(theta))
     if minsup:
         for k in range(n):
@@ -477,6 +490,7 @@ def mine_windowed(rows, values, perms, *,
             for st, peak in mp.report()["stages"].items():
                 m.gauge("pipeline_window_peak_bytes", stage=st).set(peak)
             sp.set("peak_bytes", mp.peak_bytes)
+            sp.set("peak_bytes_source", mp.source)
         sp.set("seam_carries", seam_carries)
         sp.finish()
     return P.PipelineResult(

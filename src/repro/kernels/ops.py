@@ -1,12 +1,16 @@
 """jit'd dispatch layer over the Pallas kernels.
 
 Every public op here has the same calling convention as a plain jnp
-function, chooses interpret-mode automatically off-TPU (so tests and the
-CPU container execute the *kernel body*), pads ragged inputs up to the
-kernel's block grid, and exposes ``use_pallas=False`` fall-through to the
-pure-jnp oracle in ref.py. The model layers call these ops; with
-``use_pallas=False`` (default in configs) the dry-run sees real XLA FLOPs
-(custom-call kernels are opaque to ``cost_analysis`` — DESIGN.md §7).
+function, pads ragged inputs up to the kernel's block grid, and exposes
+``use_pallas=False`` fall-through to the pure-jnp oracle in ref.py. The
+model layers call these ops; with ``use_pallas=False`` (default in
+configs) the dry-run sees real XLA FLOPs (custom-call kernels are opaque
+to ``cost_analysis`` — DESIGN.md §7).
+
+Whether a kernel is interpreted is decided in one place,
+:func:`_interpret`: the kernel body runs as interpreted HLO exactly when
+the default backend is not a TPU, so tests and the CPU container execute
+the kernel body; on a TPU every kernel is compiled by Mosaic.
 """
 from __future__ import annotations
 
@@ -23,9 +27,15 @@ from .flash_attention import flash_attention as _flash_kernel
 from .radix_sort import radix_histogram as _radix_histogram_kernel
 from .radix_sort import radix_rank as _radix_rank_kernel
 from .rmsnorm import rmsnorm as _rmsnorm_kernel
+from .segment_reduce import LANES
 from .segment_reduce import segment_reduce as _segment_reduce_kernel
 from .signature import signature as _signature_kernel
 from .tricluster_density import tricluster_density as _density_kernel
+
+#: Elements of one (8, 128) 32-bit VMEM tile: every block of the
+#: triclustering kernels is a whole number of them (Mosaic refuses 1-D
+#: blocks that do not match XLA's 1024-element tiling).
+TILE = 8 * LANES
 
 
 @functools.lru_cache(None)
@@ -34,7 +44,21 @@ def on_tpu() -> bool:
 
 
 def _interpret(flag: Optional[bool]) -> bool:
-    return not on_tpu() if flag is None else flag
+    """Interpret mode off a TPU, compiled kernels on one.  ``flag=False``
+    compiles for the TPU from a process whose backend is not one (an
+    ahead-of-time compile for a described chip); a TPU never interprets."""
+    if on_tpu():
+        if flag:
+            raise ValueError("Pallas kernels are never interpreted on a TPU")
+        return False
+    return True if flag is None else bool(flag)
+
+
+def _block_len(t: int, bt: int) -> int:
+    """Block length for a (t,) stream: whole tiles, at most ``bt``
+    rounded up to a tile, and no more tiles than ``t`` needs."""
+    tiles = lambda n: -(-max(int(n), 1) // TILE) * TILE  # noqa: E731
+    return min(tiles(bt), tiles(t))
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
@@ -127,7 +151,7 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6, *,
 # ---------------------------------------------------------------------------
 
 def segment_reduce(w_lo: jnp.ndarray, w_hi: jnp.ndarray, first: jnp.ndarray,
-                   *, bt: int = 1024, use_pallas: bool = True,
+                   *, bt: int = 64 * TILE, use_pallas: bool = True,
                    interpret: Optional[bool] = None):
     """Fused masked prefix sums for Stage-2 segment reductions.
 
@@ -135,18 +159,28 @@ def segment_reduce(w_lo: jnp.ndarray, w_hi: jnp.ndarray, first: jnp.ndarray,
     three (T,) inclusive prefix sums (uint32, uint32, int32) of the
     masked weights and of the mask — one pass instead of three
     ``segment_sum``/``cumsum`` sweeps; per-segment (or δ-window) sums
-    are then boundary differences of the prefixes."""
+    are then boundary differences of the prefixes.  ``bt`` caps the
+    block length; the kernel sees the streams as lane-dense
+    (T/128, 128) int32 rows, uint32 lanes bitcast (wrapping addition is
+    the same in both)."""
     if not use_pallas:
         return ref.segment_reduce_ref(w_lo, w_hi, first)
     t = w_lo.shape[0]
-    bt_ = min(bt, max(8, 1 << int(np.ceil(np.log2(max(t, 2))))))
-    f = first.astype(jnp.int32)
-    lo, hi, cnt = _segment_reduce_kernel(
-        _pad_to(w_lo, 0, bt_), _pad_to(w_hi, 0, bt_), _pad_to(f, 0, bt_),
-        bt=bt_, interpret=_interpret(interpret))
-    return lo[:t], hi[:t], cnt[:t]
+    blk = _block_len(t, bt)
 
-def radix_histogram(words, shifts, widths, *, bt: int = 512,
+    def rows(x):
+        x = jax.lax.bitcast_convert_type(x, jnp.int32)
+        return _pad_to(x, 0, blk).reshape(-1, LANES)
+
+    lo, hi, cnt = _segment_reduce_kernel(
+        rows(w_lo), rows(w_hi), rows(first.astype(jnp.int32)),
+        rows=blk // LANES, interpret=_interpret(interpret))
+    lo, hi, cnt = (x.reshape(-1)[:t] for x in (lo, hi, cnt))
+    return (jax.lax.bitcast_convert_type(lo, jnp.uint32),
+            jax.lax.bitcast_convert_type(hi, jnp.uint32), cnt)
+
+
+def radix_histogram(words, shifts, widths, *, bt: int = TILE,
                     use_pallas: bool = True,
                     interpret: Optional[bool] = None):
     """One-sweep histograms of every pruned radix digit position.
@@ -158,17 +192,17 @@ def radix_histogram(words, shifts, widths, *, bt: int = 512,
     if not use_pallas:
         return ref.radix_histogram_ref(words, shifts, widths)
     t = words[0].shape[0]
-    bt_ = min(bt, max(8, 1 << int(np.ceil(np.log2(max(t, 2))))))
-    pad = (-t) % bt_
+    blk = _block_len(t, bt)
+    pad = (-t) % blk
     hist = _radix_histogram_kernel(
-        [_pad_to(w, 0, bt_) for w in words], shifts=tuple(shifts),
-        widths=tuple(widths), bt=bt_, interpret=_interpret(interpret))
+        [_pad_to(w, 0, blk) for w in words], shifts=tuple(shifts),
+        widths=tuple(widths), bt=blk, interpret=_interpret(interpret))
     if pad:
         hist = hist.at[:, 0].add(-pad)
     return hist
 
 
-def radix_rank(digits: jnp.ndarray, starts: jnp.ndarray, *, bt: int = 512,
+def radix_rank(digits: jnp.ndarray, starts: jnp.ndarray, *, bt: int = TILE,
                use_pallas: bool = True,
                interpret: Optional[bool] = None) -> jnp.ndarray:
     """Stable radix-pass ranks ``starts[d_i] + occurrence_i``.
@@ -179,8 +213,8 @@ def radix_rank(digits: jnp.ndarray, starts: jnp.ndarray, *, bt: int = 512,
     if not use_pallas:
         return ref.radix_rank_ref(digits, starts)
     t = digits.shape[0]
-    bt_ = min(bt, max(8, 1 << int(np.ceil(np.log2(max(t, 2))))))
-    out = _radix_rank_kernel(_pad_to(digits, 0, bt_), starts, bt=bt_,
+    blk = _block_len(t, bt)
+    out = _radix_rank_kernel(_pad_to(digits, 0, blk), starts, bt=blk,
                              interpret=_interpret(interpret))
     return out[:t]
 

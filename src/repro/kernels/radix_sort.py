@@ -31,13 +31,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.radix import HIST_BUCKETS, extract_digit
+#: Digit width of the histogram formulation, and its bucket count.
+HIST_DIGIT_BITS = 8
+HIST_BUCKETS = 1 << HIST_DIGIT_BITS
+
+
+def extract_digit(words: Sequence[jnp.ndarray], shift: int,
+                  width: int) -> jnp.ndarray:
+    """Bits [shift, shift+width) of msb-first packed uint32 words, as a
+    uint32 digit.  ``width`` < 32 (a radix digit never spans a whole
+    word of the plan).  The one bit-field reader of every sort
+    formulation (``core.radix`` re-exports it), so the kernels can never
+    extract a different digit than the composite and reference paths."""
+    mask = jnp.uint32((1 << width) - 1)
+    if len(words) == 1:
+        return (words[0] >> shift) & mask
+    hi, lo = words
+    if shift >= 32:
+        return (hi >> (shift - 32)) & mask
+    if shift + width <= 32:
+        return (lo >> shift) & mask
+    return ((lo >> shift) | (hi << (32 - shift))) & mask
 
 
 def _digit(word_refs, shift: int, width: int):
-    """``core.radix.extract_digit`` on materialised refs — one bit-field
-    reader for every formulation, so the Pallas path can never extract a
-    different digit than the composite/reference paths."""
+    """:func:`extract_digit` on materialised refs."""
     return extract_digit(tuple(r[...] for r in word_refs), shift, width)
 
 
@@ -82,7 +100,7 @@ def _hist_kernel(*refs, bt: int, nw: int,
 
 def radix_histogram(words: Sequence[jnp.ndarray],
                     shifts: Sequence[int], widths: Sequence[int],
-                    *, bt: int = 512, interpret: bool = False):
+                    *, bt: int = 1024, interpret: bool = False):
     """All pruned digit histograms in one sweep.  words: 1-2 msb-first
     (T,) uint32 arrays, T divisible by bt -> (npass, 256) int32."""
     t = words[0].shape[0]
@@ -123,7 +141,7 @@ def _rank_kernel(dig_ref, starts_ref, out_ref, carry_ref, *, bt: int):
 
 
 def radix_rank(digits: jnp.ndarray, starts: jnp.ndarray,
-               *, bt: int = 512, interpret: bool = False):
+               *, bt: int = 1024, interpret: bool = False):
     """Stable LSD-pass ranks.  digits (T,) uint32 in [0, 256), starts
     (256,) int32 exclusive bucket starts, T divisible by bt ->
     (T,) int32 destination positions."""

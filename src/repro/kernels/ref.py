@@ -10,6 +10,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .radix_sort import HIST_BUCKETS, extract_digit
+
 
 def tricluster_density_ref(tensor: jnp.ndarray, x: jnp.ndarray,
                            y: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
@@ -54,7 +56,6 @@ def radix_histogram_ref(words, shifts, widths):
     words: 1-2 msb-first (T,) uint32 arrays; shifts/widths: the radix
     plan's per-pass digit bit ranges. Returns (npass, 256) int32.
     """
-    from ..core.radix import HIST_BUCKETS, extract_digit
     rows = []
     for shift, width in zip(shifts, widths):
         d = extract_digit(words, shift, width).astype(jnp.int32)
@@ -68,7 +69,6 @@ def radix_rank_ref(digits: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
     digits: (T,) uint32 in [0, 256); starts: (256,) int32 exclusive
     bucket starts. Returns (T,) int32 destination positions.
     """
-    from ..core.radix import HIST_BUCKETS
     oh = (digits[:, None] ==
           jnp.arange(HIST_BUCKETS, dtype=jnp.uint32)[None, :])
     oh = oh.astype(jnp.int32)

@@ -29,6 +29,7 @@ from __future__ import annotations
 import argparse
 import os
 import signal
+import subprocess
 import sys
 import tempfile
 import threading
@@ -74,6 +75,8 @@ def _install_sigterm(server, flag: dict) -> None:
 
 
 def _serve(args) -> int:
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..serve.protocol import make_server
     from ..serve.ranking import RankingPolicy
     from ..serve.service import TriclusterService
@@ -187,6 +190,8 @@ def _child_writer(cfg: dict) -> None:
     surface.  With a ``recover_dir`` a restart restores the checkpoint,
     replays the WAL tail and skips the preload — restart *is*
     recovery."""
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..serve.protocol import make_server
     from ..serve.ranking import RankingPolicy
     from ..serve.service import TriclusterService
@@ -313,6 +318,33 @@ def _child_replica(cfg: dict) -> None:
         server.drain_inflight(timeout=cfg.get("drain_timeout", 5.0))
         server.server_close()
         svc.stop()
+
+
+def _jax_backend_probe() -> str:
+    """The backend JAX picks on this host, read by a short-lived child
+    so this (router) process never loads JAX or holds a chip."""
+    proc = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=600)
+    if proc.returncode != 0:
+        raise RuntimeError(f"JAX backend probe failed: {proc.stderr[-2000:]}")
+    return proc.stdout.split()[-1]
+
+
+def _writer_chip_conflict(n_writers: int) -> str:
+    """Why ``n_writers`` mining processes cannot start here, or "".  A
+    TPU belongs to one process at a time: a second writer on a chip host
+    would hang or crash-loop under the supervisor."""
+    if n_writers < 2 or os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return ""
+    backend = _jax_backend_probe()
+    if backend != "tpu":
+        return ""
+    return (f"--shards {n_writers} starts {n_writers} writer processes that "
+            f"each mine with JAX, but JAX's backend here is {backend!r} and "
+            "a chip belongs to one process.  Serve one shard on this host "
+            "(or set JAX_PLATFORMS=cpu for a CPU plane); one process that "
+            "drives every chip is ROADMAP R1.")
 
 
 def _serve_topology(args) -> int:
@@ -627,6 +659,10 @@ def main(argv=None):
     if args.smoke_client:
         return _smoke_client(args)
     if args.shards > 1 or args.replicas > 0:
+        conflict = _writer_chip_conflict(args.shards)
+        if conflict:
+            print(f"[cluster-serve] refused: {conflict}", file=sys.stderr)
+            return 2
         return _serve_topology(args)
     return _serve(args)
 

@@ -147,8 +147,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_label: str,
                   f"out={ma.output_size_in_bytes / 1e9:.3f}GB "
                   f"temp={ma.temp_size_in_bytes / 1e9:.3f}GB "
                   f"(fits={report.fits})")
-            from ..analysis.roofline import cost_analysis_dict
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             print(f"  cost_analysis: flops={ca.get('flops', 0):.3e} "
                   f"bytes={ca.get('bytes accessed', 0):.3e}")
             print(f"  {report.row()}")

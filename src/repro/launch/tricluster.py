@@ -106,6 +106,8 @@ def main(argv=None):
                     help="timing repeats (paper used 5)")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..core import available_engines, mine
     from ..core import postprocess as PP
 

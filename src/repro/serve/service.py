@@ -912,7 +912,7 @@ class TriclusterService:
             return self
         try:
             self._remine(force=True)
-        except ValueError:
+        except RS.NoDataError:
             pass                              # no data yet: first write mines
         self._stop_evt.clear()
         self._thread = threading.Thread(target=self._loop,

@@ -4,6 +4,8 @@ import os
 
 import pytest
 
+from repro.launch import cluster_serve as cs_mod
+from repro.launch import compile_cache as cc_mod
 from repro.launch import serve as serve_mod
 from repro.launch import train as train_mod
 from repro.launch import tricluster as tri_mod
@@ -53,3 +55,36 @@ def test_serve_driver(capsys):
                            "--new-tokens", "4", "--max-len", "32"]) == 0
     out = capsys.readouterr().out
     assert "tok/s" in out
+
+
+@pytest.mark.parametrize("backend,rc", [("tpu", 2), ("cpu", 0)])
+def test_cluster_serve_one_writer_per_chip(monkeypatch, capsys, backend, rc):
+    """Several JAX writers cannot share a chip: on a TPU host a
+    multi-shard plane is refused before any process starts."""
+    started = []
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(cs_mod, "_jax_backend_probe", lambda: backend)
+    monkeypatch.setattr(cs_mod, "_serve_topology",
+                        lambda args: started.append(args.shards) or 0)
+    assert cs_mod.main(["--shards", "2"]) == rc
+    assert started == ([] if rc else [2])
+    if rc:
+        assert "ROADMAP R1" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    import jax
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = cc_mod.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == (str(tmp_path) if from_env
+                    else os.path.join(root, ".jax_cache"))

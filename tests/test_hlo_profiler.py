@@ -79,8 +79,7 @@ def test_live_scan_flops_counted_per_trip():
     # fwd dot + dx dot per layer (grad wrt x only)
     want = 2 * n_layers * 2 * d ** 3
     assert abs(prof.mxu_flops - want) / want < 0.05
-    from repro.analysis.roofline import cost_analysis_dict
-    raw = cost_analysis_dict(comp)["flops"]
+    raw = comp.cost_analysis()["flops"]
     assert prof.mxu_flops > 4 * raw   # XLA counted the body once
 
 

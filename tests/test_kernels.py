@@ -177,7 +177,12 @@ def test_exact_density_against_brute_force():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("t,bt", [(8, 8), (40, 16), (100, 32), (1024, 256),
-                                  (5000, 1024)])
+                                  (5000, 1024),
+                                  # one row, one tile less one, ragged
+                                  # blocks of one and of several tiles,
+                                  # a grid of the default 64-tile blocks
+                                  (1, 1024), (1023, 1024), (3001, 1024),
+                                  (9000, 4096), (70000, 64 * 1024)])
 def test_segment_reduce(t, bt):
     rng = np.random.default_rng(9)
     w_lo = jnp.asarray(rng.integers(0, 2**32, t, dtype=np.uint32))
@@ -198,6 +203,59 @@ def test_segment_reduce_uint32_wraparound():
     want = np.cumsum(np.full(64, 0xFFFFFFFF, np.uint64)).astype(np.uint32)
     np.testing.assert_array_equal(np.asarray(lo), want)
     np.testing.assert_array_equal(np.asarray(cnt), np.arange(1, 65))
+
+
+@pytest.mark.parametrize("t,bt", [(1, 1024), (1000, 1024), (2049, 1024),
+                                  (9000, 2048)])
+def test_segment_reduce_wraparound_blocks(t, bt):
+    """Wrap-around mod 2^32 inside a block, across the row ladder and
+    across the grid carries, under a random first-occurrence mask."""
+    rng = np.random.default_rng(t)
+    w = np.full(t, 0xFFFFFFFF, np.uint32)
+    w[::7] = 0x80000001
+    f = rng.random(t) < 0.7
+    lo, hi, cnt = ops.segment_reduce(jnp.asarray(w), jnp.asarray(w[::-1]),
+                                     jnp.asarray(f), bt=bt)
+    for got, src in ((lo, w), (hi, w[::-1])):
+        want = np.cumsum(np.where(f, src, 0).astype(np.uint64)) \
+            % (1 << 32)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      want.astype(np.uint32))
+    np.testing.assert_array_equal(np.asarray(cnt), np.cumsum(f))
+
+
+@pytest.mark.parametrize("t,bt", [(1, 1024), (1024, 1024), (1025, 1024),
+                                  (5000, 2048), (100, 1 << 20)])
+def test_block_len(t, bt):
+    """Blocks are whole (8, 128) tiles, at most ``bt`` rounded up to a
+    tile, and never more tiles than the stream needs."""
+    blk = ops._block_len(t, bt)
+    assert blk % ops.TILE == 0 and blk >= ops.TILE
+    assert blk < bt + ops.TILE
+    assert blk < t + ops.TILE
+
+
+def test_interpret_decided_by_backend(monkeypatch):
+    """Off a TPU kernels are interpreted unless a caller compiles for a
+    described chip; on a TPU they never are."""
+    assert ops._interpret(None) is True
+    assert ops._interpret(False) is False
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops._interpret(None) is False
+    with pytest.raises(ValueError):
+        ops._interpret(True)
+
+
+@pytest.mark.parametrize("module", ["repro.kernels.radix_sort",
+                                    "repro.kernels.ref", "repro.core.radix"])
+def test_radix_modules_import_first(module):
+    """Each radix module imports first in a fresh interpreter: the
+    kernels import nothing from ``core``, so there is no cycle."""
+    import subprocess
+    import sys
+    proc = subprocess.run([sys.executable, "-c", f"import {module}"],
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 def test_segment_reduce_in_pipeline():
@@ -229,7 +287,11 @@ def test_segment_reduce_in_pipeline():
 
 @pytest.mark.parametrize("t,bt,live", [(8, 8, 5), (100, 32, 22),
                                        (513, 128, 28), (1024, 256, 60),
-                                       (2000, 512, 64)])
+                                       (2000, 512, 64),
+                                       # T of one, one exact tile, and a
+                                       # ragged grid of tiles
+                                       (1, 1024, 3), (1024, 1024, 40),
+                                       (3001, 1024, 64)])
 def test_radix_histogram(t, bt, live):
     from repro.core.radix import plan_radix
     rng = np.random.default_rng(t)
@@ -245,7 +307,8 @@ def test_radix_histogram(t, bt, live):
 
 
 @pytest.mark.parametrize("t,bt", [(8, 8), (100, 32), (513, 128),
-                                  (2000, 512)])
+                                  (2000, 512), (1, 1024), (1024, 1024),
+                                  (3001, 1024)])
 def test_radix_rank(t, bt):
     rng = np.random.default_rng(t + 1)
     dig = rng.integers(0, 256, t).astype(np.uint32)

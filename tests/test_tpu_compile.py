@@ -1,0 +1,77 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip.
+
+Interpret mode accepts what Mosaic refuses (unaligned lane concatenates,
+1-D blocks whose tiling disagrees with XLA's), so every kernel the
+engines turn on by themselves on a TPU is compiled here, ahead of time,
+for one chip of a described v5e topology — no chip needed.  The
+topology is described inside a fixture: only the worker that runs this
+file loads the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ops
+
+#: a full 2^20-row table, one window of BibSonomy's 816,197 rows mined
+#: in four windows, and a small table mined whole (both ragged: not a
+#: whole number of blocks; the short one is less than one default block)
+TABLE_ROWS = (1 << 20, 204_050, 3_001)
+
+
+def _programs(t):
+    u32 = (t,), jnp.uint32
+    return {
+        "segment_reduce": (
+            lambda lo, hi, first: ops.segment_reduce(lo, hi, first,
+                                                     interpret=False),
+            [u32, u32, ((t,), jnp.bool_)]),
+        # a 48-bit two-word key: six 8-bit digit passes
+        "radix_histogram": (
+            lambda hi, lo: ops.radix_histogram(
+                (hi, lo), (0, 8, 16, 24, 32, 40), (8,) * 6,
+                interpret=False),
+            [u32, u32]),
+        "radix_rank": (
+            lambda digits, starts: ops.radix_rank(digits, starts,
+                                                  interpret=False),
+            [u32, ((256,), jnp.int32)]),
+    }
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the topology, with the persistent compilation cache
+    off: entries compiled for a described chip cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("t", TABLE_ROWS)
+@pytest.mark.parametrize("kernel", ["segment_reduce", "radix_histogram",
+                                    "radix_rank"])
+def test_kernel_compiles_for_v5e(one_chip, kernel, t):
+    fn, shapes = _programs(t)[kernel]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
