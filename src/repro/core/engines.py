@@ -19,6 +19,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from ..obs.phases import phase
 from .batch import BatchMiner
 from .context import PolyadicContext
 from .distributed import DistributedMiner, pad_tuples, pad_values
@@ -68,12 +69,7 @@ class MineRun:
     rerun: Any = None            # zero-arg warm re-execution of the mining
                                  # step (no re-compile); returns the result
                                  # and records its time in ``rerun.last_s``
-    _n_tuples: int = 0
     _clusters: Any = None        # list, or a zero-arg callable making it
-
-    @property
-    def tuples_per_s(self) -> float:
-        return 0.0 if not self.elapsed_s else self._n_tuples / self.elapsed_s
 
     @property
     def clusters(self) -> Optional[list]:
@@ -117,8 +113,7 @@ def mine(ctx: PolyadicContext, backend: str = "batch",
     elapsed = getattr(rerun, "last_s", None) or total
     return MineRun(backend=backend, variant=variant, n_clusters=n_clusters,
                    elapsed_s=elapsed, result=result, miner=miner,
-                   rerun=rerun, _n_tuples=ctx.num_tuples,
-                   _clusters=clusters)
+                   rerun=rerun, _clusters=clusters)
 
 
 def _noac_ctx(ctx: PolyadicContext) -> PolyadicContext:
@@ -147,12 +142,14 @@ def _pipe_kw(p):
 
 def _timed(step, block=True):
     """Wrap a mining step: each call blocks on the device result (when it
-    has one) and records its wall time in ``go.last_s``."""
+    has one, in a ``mine.wait`` phase) and records its wall time in
+    ``go.last_s``."""
     def go():
         t0 = time.perf_counter()
         out = step()
         if block:
-            np.asarray(out.keep)
+            with phase("mine.wait"):
+                np.asarray(out.keep)
         go.last_s = time.perf_counter() - t0
         return out
     go.last_s = None

@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -50,7 +49,9 @@ import numpy as np
 
 # the shared shard_map call (canonical home: repro._compat)
 from .._compat import shard_map  # noqa: F401  (re-export for the engines)
+from .._compat import cumulative
 from ..kernels import ops as kops
+from ..obs.phases import phase
 from . import keys as K
 from . import radix as RX
 
@@ -122,8 +123,9 @@ def segment_bounds(flags: jnp.ndarray):
     backends."""
     t = flags.shape[0]
     pos = jnp.arange(t, dtype=jnp.int32)
-    a = jax.lax.cummax(jnp.where(flags, pos, 0))
-    suff = jax.lax.cummin(jnp.where(flags, pos, jnp.int32(t)), reverse=True)
+    a = cumulative(jnp.where(flags, pos, 0), jax.lax.max)
+    suff = cumulative(jnp.where(flags, pos, jnp.int32(t)), jax.lax.min,
+                      reverse=True)
     b = jnp.concatenate([suff[1:], jnp.full((1,), t, jnp.int32)])
     return a, b
 
@@ -325,7 +327,20 @@ def delta_components(sm: SortedMode, r_lo: jnp.ndarray, r_hi: jnp.ndarray,
     differences exact)."""
     pref_lo, pref_hi, pref_cnt = masked_prefix(
         r_lo[sm.sorted_e], r_hi[sm.sorted_e], sm.first_occ, use_pallas)
-    # per-tuple query window inside its own segment
+    with jax.named_scope("delta_search"):
+        lo_idx, hi_idx = _delta_bounds(sm, values, delta, value_domain)
+    return ModeComponents(pref_lo[hi_idx] - pref_lo[lo_idx],
+                          pref_hi[hi_idx] - pref_hi[lo_idx],
+                          pref_cnt[hi_idx] - pref_cnt[lo_idx],
+                          lo_idx.astype(jnp.int32),
+                          hi_idx.astype(jnp.int32))
+
+
+def _delta_bounds(sm: SortedMode, values: jnp.ndarray, delta: float,
+                  value_domain: Optional[jnp.ndarray]):
+    """Per tuple, in original order: the [lo, hi) window of sorted
+    positions whose value lies within δ of the tuple's own, inside its
+    key segment."""
     if sm.sorted_words is not None and sm.plan is not None \
             and sm.plan.with_values:
         # packed path: δ-window bounds by *global* search over the sorted
@@ -363,11 +378,7 @@ def delta_components(sm: SortedMode, r_lo: jnp.ndarray, r_hi: jnp.ndarray,
                          leq=False)
         hi_idx = bsearch(sm.sorted_vals, a, b, values + jnp.float32(delta),
                          leq=True)
-    return ModeComponents(pref_lo[hi_idx] - pref_lo[lo_idx],
-                          pref_hi[hi_idx] - pref_hi[lo_idx],
-                          pref_cnt[hi_idx] - pref_cnt[lo_idx],
-                          lo_idx.astype(jnp.int32),
-                          hi_idx.astype(jnp.int32))
+    return lo_idx, hi_idx
 
 
 # ---------------------------------------------------------------------------
@@ -403,7 +414,7 @@ def stage3_dedup(sig_lo: jnp.ndarray, sig_hi: jnp.ndarray,
     # unique representative iff it is the window's first s_first entry.
     pref = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(s_first.astype(jnp.int32), dtype=jnp.int32)])
+         cumulative(s_first.astype(jnp.int32), jax.lax.add)])
     pos = jnp.arange(t, dtype=jnp.int32)
     uniq_sorted = s_first & (pref[pos] == pref[a])
     # one inverse-permutation scatter + two gathers (scatters dominate
@@ -451,6 +462,11 @@ def density_of(gen_count: jnp.ndarray, volume: jnp.ndarray) -> jnp.ndarray:
     return gen_count.astype(jnp.float32) / jnp.maximum(volume, 1.0)
 
 
+#: the top-level named scopes of ``mine_tuples``, in pipeline order
+STAGE_SCOPES = ("stage1_sort", "stage2_components", "stage2_mix",
+                "stage3_dedup")
+
+
 def mine_tuples(tuples: jnp.ndarray, hash_lo: Sequence[jnp.ndarray],
                 hash_hi: Sequence[jnp.ndarray], *,
                 values: Optional[jnp.ndarray] = None,
@@ -479,7 +495,12 @@ def mine_tuples(tuples: jnp.ndarray, hash_lo: Sequence[jnp.ndarray],
     of the many-valued column, when the caller knows them — prunes the
     key's value lane to rank width (``core.keys``), shrinking the radix
     pass schedule; orderings are unchanged (rank coding is
-    order-isomorphic), so all sort paths stay bit-identical."""
+    order-isomorphic), so all sort paths stay bit-identical.
+
+    Every operation lies under one of the :data:`STAGE_SCOPES` named
+    scopes (``delta_search`` nests inside ``stage2_components``), which
+    the compiled program carries as each instruction's ``op_name`` and a
+    profiler trace therefore attributes device time by."""
     t, n = tuples.shape
     if delta is not None and delta < 0:
         raise ValueError(f"delta must be >= 0, got {delta}")
@@ -498,46 +519,54 @@ def mine_tuples(tuples: jnp.ndarray, hash_lo: Sequence[jnp.ndarray],
     s3_backend = RX.resolve_sort_backend(sort_backend, packed, True)
     comps, sms = [], []
     for k in range(n):
-        sm = sort_mode(tuples, k, values=values,
-                       perm=None if perms is None else perms[k],
-                       plan=plans[k] if use_packed else None,
-                       sort_backend=backend, use_pallas=use_pallas,
-                       value_domain=value_domain)
-        if delta is None:
-            comps.append(prime_components(sm, hash_lo[k], hash_hi[k],
-                                          use_pallas))
-        else:
-            comps.append(delta_components(sm, hash_lo[k], hash_hi[k],
-                                          values, delta, use_pallas,
-                                          value_domain=value_domain))
+        with jax.named_scope("stage1_sort"):
+            sm = sort_mode(tuples, k, values=values,
+                           perm=None if perms is None else perms[k],
+                           plan=plans[k] if use_packed else None,
+                           sort_backend=backend, use_pallas=use_pallas,
+                           value_domain=value_domain)
+        with jax.named_scope("stage2_components"):
+            if delta is None:
+                comps.append(prime_components(sm, hash_lo[k], hash_hi[k],
+                                              use_pallas))
+            else:
+                comps.append(delta_components(sm, hash_lo[k], hash_hi[k],
+                                              values, delta, use_pallas,
+                                              value_domain=value_domain))
         sms.append(sm)
+    with jax.named_scope("stage1_sort"):
+        sorted_e = jnp.stack([sm.sorted_e for sm in sms])
+        perms_out = jnp.stack([sm.perm.astype(jnp.int32) for sm in sms])
+    with jax.named_scope("stage2_components"):
+        cards = jnp.stack([c.card for c in comps])
+        range_lo = jnp.stack([c.range_lo for c in comps])
+        range_hi = jnp.stack([c.range_hi for c in comps])
     # Stage 2: per-tuple cluster = mix of per-mode component aggregates.
-    sig_lo, sig_hi = mix_signatures([c.sig_lo for c in comps],
-                                    [c.sig_hi for c in comps])
-    volume = jnp.ones((t,), jnp.float32)
-    for c in comps:
-        volume = volume * c.card.astype(jnp.float32)
+    with jax.named_scope("stage2_mix"):
+        sig_lo, sig_hi = mix_signatures([c.sig_lo for c in comps],
+                                        [c.sig_hi for c in comps])
+        volume = jnp.ones((t,), jnp.float32)
+        for c in comps:
+            volume = volume * c.card.astype(jnp.float32)
     # Stage 3.  Mode 0's sort key covers the whole row, so its
     # first-of-run flags already mark the lowest-index copy of each
     # duplicate row (stable sorts) — no extra full-table sort needed;
     # gathering through mode 0's inverse permutation avoids a scatter.
-    tfirst = sms[0].first_occ[sms[0].inv]
-    gen_of, is_unique = stage3_dedup(sig_lo, sig_hi, tfirst,
-                                     packed=s3_backend != "lexsort",
-                                     sort_backend=s3_backend,
-                                     use_pallas=use_pallas)
-    density = density_of(gen_of, volume)
-    keep = is_unique & (density >= jnp.float32(theta))
-    if minsup:
-        for c in comps:
-            keep = keep & (c.card >= minsup)
+    with jax.named_scope("stage3_dedup"):
+        tfirst = sms[0].first_occ[sms[0].inv]
+        gen_of, is_unique = stage3_dedup(sig_lo, sig_hi, tfirst,
+                                         packed=s3_backend != "lexsort",
+                                         sort_backend=s3_backend,
+                                         use_pallas=use_pallas)
+        density = density_of(gen_of, volume)
+        keep = is_unique & (density >= jnp.float32(theta))
+        if minsup:
+            for c in comps:
+                keep = keep & (c.card >= minsup)
     return PipelineResult(
         sig_lo, sig_hi, is_unique, gen_of, volume, density, keep,
-        cardinalities=jnp.stack([c.card for c in comps]),
-        range_lo=jnp.stack([c.range_lo for c in comps]),
-        range_hi=jnp.stack([c.range_hi for c in comps]),
-        sorted_e=jnp.stack([sm.sorted_e for sm in sms]),
-        perms=jnp.stack([sm.perm.astype(jnp.int32) for sm in sms]))
+        cardinalities=cards, range_lo=range_lo, range_hi=range_hi,
+        sorted_e=sorted_e, perms=perms_out)
 
 
 # ---------------------------------------------------------------------------
@@ -589,10 +618,22 @@ def dirty_sig_count(prev: Optional[np.ndarray],
 def _active_obs(obs):
     """The enabled observability hub or None — the pipeline's
     zero-overhead-when-disabled gate.  Duck-typed (``.enabled``,
-    ``.metrics``, ``.tracer``) so ``core`` never imports ``repro.obs``;
-    callers pass a ``repro.obs.Obs`` (or nothing)."""
+    ``.metrics``, ``.tracer``): ``core`` imports only ``repro.obs``'s
+    ``phase`` helper; callers pass a ``repro.obs.Obs`` (or nothing)."""
     return obs if (obs is not None
                    and getattr(obs, "enabled", False)) else None
+
+
+def _copy_in(obs, tuples, values):
+    """The table on the device: int32 tuples, float32 values (or None),
+    in one ``mine.copy_in`` phase whose ``bytes`` is what is copied."""
+    n = int(np.prod(np.shape(tuples))) + (
+        0 if values is None else int(np.prod(np.shape(values))))
+    with phase("mine.copy_in", obs, bytes=4 * n):
+        tuples = jnp.asarray(tuples, jnp.int32)
+        if values is not None:
+            values = jnp.asarray(values, jnp.float32)
+    return tuples, values
 
 
 class PipelineMiner:
@@ -601,10 +642,15 @@ class PipelineMiner:
     Subclasses (``BatchMiner``, ``NOACMiner``) pin the component operator;
     everything else — hashing, jit caching, materialisation — is shared.
 
-    ``obs`` (an enabled ``repro.obs.Obs``) turns on per-stage wall-time
-    profiling: host run-sort vs device mine split, per-window stage
-    timings and memory peaks on the windowed path.  ``obs=None`` (the
-    default) keeps every hot loop at a single predicate test."""
+    Every mine marks its host phases as ``repro.*`` profiler spans
+    (``obs.phase``): ``mine.value_domain`` (NOAC), ``mine.copy_in``,
+    ``mine.dispatch``, ``mine.wait`` and, on the chunked and windowed
+    paths, the host run sort ``stage1_sort``.  ``obs`` (an enabled
+    ``repro.obs.Obs``) also times each phase into
+    ``pipeline_stage_ms{stage}``, waits for the device inside the call
+    and, on the windowed path, records per-window stage timings and
+    memory peaks.  ``obs=None`` (the default) costs one TraceMe a
+    phase."""
 
     def __init__(self, sizes: Sequence[int], *, theta: float = 0.0,
                  delta: Optional[float] = None, minsup: int = 0,
@@ -661,28 +707,30 @@ class PipelineMiner:
 
     def __call__(self, tuples, values=None) -> PipelineResult:
         obs = _active_obs(self.obs)
-        t0 = time.perf_counter() if obs is not None else 0.0
-        tuples = jnp.asarray(tuples, jnp.int32)
+        vdom = None
         if self.delta is not None:
             if values is None:
-                values = jnp.zeros((tuples.shape[0],), jnp.float32)
+                values = np.zeros((np.shape(tuples)[0],), np.float32)
             # domain from the caller's (usually host-side) array, before
             # the device transfer — np.unique never round-trips the
             # device column
-            vdom = self.value_domain(values)
-            values = jnp.asarray(values, jnp.float32)
+            with phase("mine.value_domain", obs):
+                vdom = self.value_domain(values)
         else:
-            values, vdom = None, None
-        res = self._fn(tuples, self._lo, self._hi, values=values,
-                       value_domain=vdom)
+            values = None
+        tuples, values = _copy_in(obs, tuples, values)
+        return self._dispatch(obs, tuples, values=values, value_domain=vdom)
+
+    def _dispatch(self, obs, tuples, **kw) -> PipelineResult:
+        """Launch the jitted pipeline; with a hub, wait for it."""
+        with phase("mine.dispatch", obs):
+            res = self._fn(tuples, self._lo, self._hi, **kw)
         if obs is not None:
             # profiling forces the async dispatch to completion: the
             # measured figure is the real device wall time, and the
             # next stage's timer starts clean
-            jax.block_until_ready(res)
-            obs.metrics.histogram(
-                "pipeline_stage_ms", stage="mine_monolithic").observe(
-                    (time.perf_counter() - t0) * 1e3)
+            with phase("mine.wait", obs):
+                jax.block_until_ready(res)
         return res
 
     def materialise(self, result: PipelineResult, tuples=None,
@@ -720,42 +768,31 @@ class PipelineMiner:
                 f"chunk_budget must be >= 1, got {chunk_budget}; pass "
                 "None to ingest chunks as offered")
         obs = _active_obs(self.obs)
-        t0 = time.perf_counter() if obs is not None else 0.0
-        store = RS.RunStore(self.key_plans,
-                            radix=self.resolved_sort_backend == "radix",
-                            incremental=self.key_plans[0].fits,
-                            stats=stats if stats is not None else {})
-        for rows, vals in RS.iter_chunks(chunks, values, chunk_budget,
-                                         with_values=self.delta is not None):
-            store.add(rows, vals)
-        store.prepare()
-        if obs is not None:
-            # the host run sort IS Stage 1's sort on this path
-            obs.metrics.histogram(
-                "pipeline_stage_ms", stage="stage1_sort").observe(
-                    (time.perf_counter() - t0) * 1e3)
-            t0 = time.perf_counter()
+        # the host run sort IS Stage 1's sort on this path
+        with phase("stage1_sort", obs):
+            store = RS.RunStore(self.key_plans,
+                                radix=self.resolved_sort_backend == "radix",
+                                incremental=self.key_plans[0].fits,
+                                stats=stats if stats is not None else {})
+            for rows, vals in RS.iter_chunks(
+                    chunks, values, chunk_budget,
+                    with_values=self.delta is not None):
+                store.add(rows, vals)
+            store.prepare()
         if store.count == 0:
             raise ValueError("no data ingested")
         rows, vals = store.table()
-        targs = jnp.asarray(rows, jnp.int32)
-        vargs = None if vals is None else jnp.asarray(vals, jnp.float32)
         perms = store.perms()
         if perms is None:      # key exceeds 64 bits: no host runs
             # one device sort of the assembled table — with the same
             # value-lane pruning __call__ applies, so a key rescued by
             # the rank-coded lane still takes the packed path
-            res = self._fn(targs, self._lo, self._hi, values=vargs,
-                           value_domain=self.value_domain(vals))
+            with phase("mine.value_domain", obs):
+                kw = {"value_domain": self.value_domain(vals)}
         else:
-            res = self._fn(targs, self._lo, self._hi, values=vargs,
-                           perms=jnp.asarray(perms, jnp.int32))
-        if obs is not None:
-            jax.block_until_ready(res)
-            obs.metrics.histogram(
-                "pipeline_stage_ms", stage="device_mine").observe(
-                    (time.perf_counter() - t0) * 1e3)
-        return res
+            kw = {"perms": jnp.asarray(perms, jnp.int32)}
+        targs, vargs = _copy_in(obs, rows, vals)
+        return self._dispatch(obs, targs, values=vargs, **kw)
 
     def mine_windowed(self, chunks, values=None,
                       window_budget: Optional[int] = None,
@@ -793,18 +830,15 @@ class PipelineMiner:
                 f"window_budget must be >= 1, got {window_budget}; "
                 "pass None for a single in-core window")
         obs = _active_obs(self.obs)
-        t0 = time.perf_counter() if obs is not None else 0.0
-        store = RS.RunStore(self.key_plans, radix=backend == "radix",
-                            incremental=True,
-                            stats=stats if stats is not None else {})
-        for rows, vals in RS.iter_chunks(chunks, values, window_budget,
-                                         with_values=self.delta is not None):
-            store.add(rows, vals)
-        store.prepare()
-        if obs is not None:
-            obs.metrics.histogram(
-                "pipeline_stage_ms", stage="stage1_sort").observe(
-                    (time.perf_counter() - t0) * 1e3)
+        with phase("stage1_sort", obs):
+            store = RS.RunStore(self.key_plans, radix=backend == "radix",
+                                incremental=True,
+                                stats=stats if stats is not None else {})
+            for rows, vals in RS.iter_chunks(
+                    chunks, values, window_budget,
+                    with_values=self.delta is not None):
+                store.add(rows, vals)
+            store.prepare()
         if store.count == 0:
             raise ValueError("no data ingested")
         rows, vals = store.table()

@@ -49,6 +49,7 @@ import numpy as np
 
 # the histogram formulation's digit and the shared bit-field reader live
 # with its kernels, which import nothing from ``core``
+from .._compat import cumulative
 from ..kernels.radix_sort import HIST_DIGIT_BITS, extract_digit
 
 #: Valid values of the ``sort_backend`` selector threaded through the
@@ -130,7 +131,7 @@ def _perm_histogram(words, plan: RadixPlan, use_pallas: bool) -> jnp.ndarray:
     for p, (shift, width) in enumerate(zip(plan.shifts, plan.widths)):
         starts = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32),
-             jnp.cumsum(hists[p], dtype=jnp.int32)[:-1]])
+             cumulative(hists[p], jax.lax.add)[:-1]])
         dig = extract_digit(words, shift, width)
         if perm is not None:
             dig = dig[perm]

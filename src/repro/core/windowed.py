@@ -58,7 +58,6 @@ results through ``np.asarray`` anyway.
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
@@ -66,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from ..obs.phases import phase
 from . import keys as K
 from . import pipeline as P
 from . import radix as RX
@@ -241,6 +241,13 @@ def _kway_combine(parts):
 # The windowed driver
 # ---------------------------------------------------------------------------
 
+def _window_phase(stage: str, prof):
+    """One device window of ``stage``: a ``repro.window.<stage>`` span,
+    timed into ``pipeline_window_ms{stage}`` when ``prof`` is a hub."""
+    return phase(f"window.{stage}", prof, metric="pipeline_window_ms",
+                 stage=stage)
+
+
 def mine_windowed(rows, values, perms, *,
                   plans: Sequence[K.ModeKeyPlan],
                   hash_lo, hash_hi,
@@ -271,8 +278,9 @@ def mine_windowed(rows, values, perms, *,
     histograms, the seam-carry count (windows entered mid-segment),
     and — when no ``probe`` is supplied — a ``core.memprobe`` peak
     sample per stage, all folded into the hub's registry plus one
-    ``pipeline.windowed`` span.  ``obs=None`` keeps the loop at one
-    predicate test per window.
+    ``pipeline.windowed`` span.  Each device window of each stage is a
+    ``repro.window.<stage>`` profiler span either way; ``obs=None``
+    adds nothing else to the loop.
 
     Raises ``ValueError`` for degenerate budgets (< 1) and for
     configurations the windowed path cannot honour bit-exactly
@@ -320,9 +328,6 @@ def mine_windowed(rows, values, perms, *,
             from . import memprobe as MP
             mp = MP.MemProbe()
             probe = mp
-        win_hist = {st: prof.metrics.histogram("pipeline_window_ms",
-                                               stage=st)
-                    for st in STAGES}
         stage_ms = {st: 0.0 for st in STAGES}
         seam_carries = 0
         sp = prof.tracer.start("pipeline.windowed", rows=t, modes=n,
@@ -349,25 +354,23 @@ def mine_windowed(rows, values, perms, *,
         pref_cnt = np.zeros(t + 1, np.int32)
         c_lo, c_hi, c_cnt = (jnp.uint32(0), jnp.uint32(0), jnp.int32(0))
         for w0, w1 in wplan.bounds:
-            tw = time.perf_counter() if prof is not None else 0.0
-            win = _pad_tail(sk[w0:w1], budget)
-            words = tuple(jnp.asarray(w) for w in
-                          _split_words(win, plan.words))
-            first0 = bool(w0 == 0 or sk[w0] != sk[w0 - 1])
-            f0 = jnp.asarray(first0)
-            lo, hi, cnt, c_lo, c_hi, c_cnt = scan(
-                words, f0, c_lo, c_hi, c_cnt, hash_lo[k], hash_hi[k])
-            pref_lo[w0 + 1:w1 + 1] = np.asarray(lo)[:w1 - w0]
-            pref_hi[w0 + 1:w1 + 1] = np.asarray(hi)[:w1 - w0]
-            pref_cnt[w0 + 1:w1 + 1] = np.asarray(cnt)[:w1 - w0]
-            if probe is not None:
-                probe("stage1_scan")
+            with _window_phase("stage1_scan", prof) as ph:
+                win = _pad_tail(sk[w0:w1], budget)
+                words = tuple(jnp.asarray(w) for w in
+                              _split_words(win, plan.words))
+                first0 = bool(w0 == 0 or sk[w0] != sk[w0 - 1])
+                f0 = jnp.asarray(first0)
+                lo, hi, cnt, c_lo, c_hi, c_cnt = scan(
+                    words, f0, c_lo, c_hi, c_cnt, hash_lo[k], hash_hi[k])
+                pref_lo[w0 + 1:w1 + 1] = np.asarray(lo)[:w1 - w0]
+                pref_hi[w0 + 1:w1 + 1] = np.asarray(hi)[:w1 - w0]
+                pref_cnt[w0 + 1:w1 + 1] = np.asarray(cnt)[:w1 - w0]
+                if probe is not None:
+                    probe("stage1_scan")
             if prof is not None:
                 if not first0:      # entered mid-segment: a seam carry
                     seam_carries += 1
-                ms = (time.perf_counter() - tw) * 1e3
-                stage_ms["stage1_scan"] += ms
-                win_hist["stage1_scan"].observe(ms)
+                stage_ms["stage1_scan"] += ph.ms
         # component windows in sorted order: whole key segment (prime)
         # or the δ-value range inside it (NOAC, global self-clamping
         # search — the host twin of keys.search_words)
@@ -406,49 +409,46 @@ def mine_windowed(rows, values, perms, *,
     sig_hi = np.empty(t, np.uint32)
     volume = np.empty(t, np.float32)
     for w0, w1 in wplan.bounds:
-        tw = time.perf_counter() if prof is not None else 0.0
-        wl = w1 - w0
-        pad = budget - wl
-        slo = np.pad(mode_sig_lo[:, w0:w1], ((0, 0), (0, pad)))
-        shi = np.pad(mode_sig_hi[:, w0:w1], ((0, 0), (0, pad)))
-        scd = np.pad(mode_card[:, w0:w1], ((0, 0), (0, pad)))
-        lo, hi, vol = mixfn(jnp.asarray(slo), jnp.asarray(shi),
-                            jnp.asarray(scd))
-        sig_lo[w0:w1] = np.asarray(lo)[:wl]
-        sig_hi[w0:w1] = np.asarray(hi)[:wl]
-        volume[w0:w1] = np.asarray(vol)[:wl]
-        if probe is not None:
-            probe("stage2_mix")
+        with _window_phase("stage2_mix", prof) as ph:
+            wl = w1 - w0
+            pad = budget - wl
+            slo = np.pad(mode_sig_lo[:, w0:w1], ((0, 0), (0, pad)))
+            shi = np.pad(mode_sig_hi[:, w0:w1], ((0, 0), (0, pad)))
+            scd = np.pad(mode_card[:, w0:w1], ((0, 0), (0, pad)))
+            lo, hi, vol = mixfn(jnp.asarray(slo), jnp.asarray(shi),
+                                jnp.asarray(scd))
+            sig_lo[w0:w1] = np.asarray(lo)[:wl]
+            sig_hi[w0:w1] = np.asarray(hi)[:wl]
+            volume[w0:w1] = np.asarray(vol)[:wl]
+            if probe is not None:
+                probe("stage2_mix")
         if prof is not None:
-            ms = (time.perf_counter() - tw) * 1e3
-            stage_ms["stage2_mix"] += ms
-            win_hist["stage2_mix"].observe(ms)
+            stage_ms["stage2_mix"] += ph.ms
 
     # ---- Stage 3: per-window device signature sorts + host combine
     s3fn = _s3_fn(sort_backend, use_pallas)
     parts = []
     for w0, w1 in wplan.bounds:
-        tw = time.perf_counter() if prof is not None else 0.0
-        wl = w1 - w0
-        s_lo, s_hi, idx = s3fn(
-            jnp.asarray(_pad_tail(sig_lo[w0:w1], budget, fill=0)),
-            jnp.asarray(_pad_tail(sig_hi[w0:w1], budget, fill=0)))
-        s_lo, s_hi = np.asarray(s_lo), np.asarray(s_hi)
-        idx = np.asarray(idx)
-        # drop tail pads: a stable sort's real-element subsequence is
-        # exactly the stable sort of the real elements alone
-        m = idx < wl
-        # the Stage-3 sort keys (sig_lo, sig_hi) msb-first — sig_lo is
-        # the high word of the packed signature the combine merges on
-        word = ((s_lo[m].astype(np.uint64) << np.uint64(32))
-                | s_hi[m].astype(np.uint64))
-        parts.append((word, (w0 + idx[m]).astype(np.int64)))
-        if probe is not None:
-            probe("stage3_sort")
+        with _window_phase("stage3_sort", prof) as ph:
+            wl = w1 - w0
+            s_lo, s_hi, idx = s3fn(
+                jnp.asarray(_pad_tail(sig_lo[w0:w1], budget, fill=0)),
+                jnp.asarray(_pad_tail(sig_hi[w0:w1], budget, fill=0)))
+            s_lo, s_hi = np.asarray(s_lo), np.asarray(s_hi)
+            idx = np.asarray(idx)
+            # drop tail pads: a stable sort's real-element subsequence
+            # is exactly the stable sort of the real elements alone
+            m = idx < wl
+            # the Stage-3 sort keys (sig_lo, sig_hi) msb-first — sig_lo
+            # is the high word of the packed signature the combine
+            # merges on
+            word = ((s_lo[m].astype(np.uint64) << np.uint64(32))
+                    | s_hi[m].astype(np.uint64))
+            parts.append((word, (w0 + idx[m]).astype(np.int64)))
+            if probe is not None:
+                probe("stage3_sort")
         if prof is not None:
-            ms = (time.perf_counter() - tw) * 1e3
-            stage_ms["stage3_sort"] += ms
-            win_hist["stage3_sort"].observe(ms)
+            stage_ms["stage3_sort"] += ph.ms
     s_word, order = _kway_combine(parts)
     # group stats on the combined order — the monolithic stage3_dedup
     # prefix-difference formulas on host

@@ -117,6 +117,7 @@ def radix_histogram(words: Sequence[jnp.ndarray],
         out_shape=jax.ShapeDtypeStruct((npass, HIST_BUCKETS), jnp.int32),
         scratch_shapes=[pltpu.VMEM((npass, HIST_BUCKETS), jnp.int32)],
         interpret=interpret,
+        name="radix_histogram",
     )(*words)
 
 
@@ -156,4 +157,5 @@ def radix_rank(digits: jnp.ndarray, starts: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((t,), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, HIST_BUCKETS), jnp.int32)],
         interpret=interpret,
+        name="radix_rank",
     )(digits, starts)

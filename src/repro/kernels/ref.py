@@ -10,6 +10,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .._compat import cumulative
 from .radix_sort import HIST_BUCKETS, extract_digit
 
 
@@ -44,9 +45,9 @@ def segment_reduce_ref(w_lo: jnp.ndarray, w_hi: jnp.ndarray,
     Returns ((T,) uint32, (T,) uint32, (T,) int32).
     """
     f = first.astype(bool)
-    lo = jnp.cumsum(jnp.where(f, w_lo, jnp.uint32(0)), dtype=jnp.uint32)
-    hi = jnp.cumsum(jnp.where(f, w_hi, jnp.uint32(0)), dtype=jnp.uint32)
-    cnt = jnp.cumsum(f.astype(jnp.int32), dtype=jnp.int32)
+    lo = cumulative(jnp.where(f, w_lo, jnp.uint32(0)), jax.lax.add)
+    hi = cumulative(jnp.where(f, w_hi, jnp.uint32(0)), jax.lax.add)
+    cnt = cumulative(f.astype(jnp.int32), jax.lax.add)
     return lo, hi, cnt
 
 
@@ -72,7 +73,7 @@ def radix_rank_ref(digits: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
     oh = (digits[:, None] ==
           jnp.arange(HIST_BUCKETS, dtype=jnp.uint32)[None, :])
     oh = oh.astype(jnp.int32)
-    occ = jnp.cumsum(oh, axis=0, dtype=jnp.int32) - oh
+    occ = cumulative(oh, jax.lax.add, axis=0) - oh
     return (oh * (occ + starts[None, :])).sum(axis=1)
 
 

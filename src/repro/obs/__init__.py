@@ -13,6 +13,10 @@ Three instruments, one hub:
   with trace id, coverage and queue-wait/handler split
   (``/debug/slow``).
 
+:class:`~repro.obs.phases.phase` marks a host phase of the mining path
+on the profiler's clock (``repro.*`` spans) and, with an enabled hub,
+times it into ``pipeline_stage_ms``.
+
 :class:`Obs` bundles the three for threading through the serving
 plane; ``Obs.create(...)`` builds an enabled hub, :data:`NULL_OBS` is
 the shared disabled hub whose instruments are all no-ops — passing
@@ -25,6 +29,7 @@ from typing import Optional
 
 from .metrics import (DEFAULT_BUCKET_RATIO, NULL, Counter, Gauge,
                       Histogram, NullInstrument, Registry)
+from .phases import phase
 from .trace import (NULL_TRACER, TRACE_HEADER, SlowQueryLog, Span,
                     Tracer, format_trace_header, parse_trace_header)
 
@@ -34,6 +39,7 @@ __all__ = [
     "NULL", "DEFAULT_BUCKET_RATIO",
     "Tracer", "Span", "SlowQueryLog", "TRACE_HEADER", "NULL_TRACER",
     "parse_trace_header", "format_trace_header",
+    "phase",
 ]
 
 

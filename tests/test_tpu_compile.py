@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e chip.
+"""The main-path Pallas kernels compile for a TPU v5e chip, under their
+own names.
 
 Interpret mode accepts what Mosaic refuses (unaligned lane concatenates,
 1-D blocks whose tiling disagrees with XLA's), so every kernel the
@@ -8,6 +9,7 @@ topology is described inside a fixture: only the worker that runs this
 file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,3 +77,49 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, t):
             for shape, dtype in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    # the kernel's name= is its instruction's name, which a trace shows
+    assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text)
+
+
+@pytest.mark.parametrize("variant", ["prime", "noac"])
+def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
+                                                 variant):
+    """Compiled for the chip with its Mosaic kernels, every operation of
+    a mine that carries the program's ``op_name`` lies under a stage
+    scope: the scans' fusions too, which ``lax.cum*`` would leave
+    unscoped (XLA's own reduce-window trees and copies carry none)."""
+    from repro.core import BatchMiner, NOACMiner
+    from repro.core.pipeline import STAGE_SCOPES
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    t = 8192
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if variant == "prime":
+        m, kw = BatchMiner((2337, 67464, 28920), use_pallas=True), {}
+    else:
+        m = NOACMiner((6040, 3952, 5), delta=1.0, use_pallas=True)
+        kw = {"values": sds((t,), jnp.float32),
+              "value_domain": sds((5,), jnp.float32)}
+    text = m._fn.lower(sds((t, 3), jnp.int32),
+                       [sds(h.shape, h.dtype) for h in m._lo],
+                       [sds(h.shape, h.dtype) for h in m._hi],
+                       **kw).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    named = merged = 0
+    for line in entry.splitlines()[1:]:
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([a-z][\w-]*)\(", line)
+        if name is None or op.group(1) in ("parameter", "constant", "tuple",
+                                           "get-tuple-element", "copy"):
+            continue
+        path = name.group(1).split("/")
+        stages = sum(s in path for s in STAGE_SCOPES)
+        assert stages >= 1, line[:300]
+        # XLA's CSE may merge the same work of two stages (the NaN test
+        # of the value column inside two ``jnp.searchsorted`` calls); a
+        # trace gives it to the first stage of its path
+        merged += stages > 1
+        named += 1
+    assert named > 100 and merged <= 0.01 * named, (merged, named)
