@@ -336,6 +336,12 @@ def sort_with_payload(words: Sequence[jnp.ndarray],
     return out[:nw], out[nw:]
 
 
+def search_steps(t: int) -> int:
+    """Dependent steps of a binary search over ``t`` sorted keys —
+    each one a gather of every query's probe (:func:`search_words`)."""
+    return max(1, int(np.ceil(np.log2(max(t, 2)))) + 1)
+
+
 def search_words(s_words: Sequence[jnp.ndarray],
                  q_words: Sequence[jnp.ndarray], upper: bool) -> jnp.ndarray:
     """Vectorised binary search over sorted packed keys.  Returns, per
@@ -343,7 +349,7 @@ def search_words(s_words: Sequence[jnp.ndarray],
     (lower bound); T if none.  Keys compare lexicographically over the
     msb-first word tuples."""
     t = s_words[0].shape[0]
-    iters = max(1, int(np.ceil(np.log2(max(t, 2)))) + 1)
+    iters = search_steps(t)
     lo = jnp.zeros(q_words[0].shape, jnp.int32)
     hi = jnp.full(q_words[0].shape, t, jnp.int32)
     for _ in range(iters):

@@ -3,8 +3,9 @@
 A thin driver over the shared Stage-1/2/3 pipeline (``core.pipeline``,
 DESIGN.md §3) with the *δ-range* component operator: each mode's table is
 sorted by (other columns, value), so every δ-cumulus is a contiguous
-value range inside a contiguous key segment, found with two vectorised
-binary searches — O(T log T) total, versus the O(T · |A_k|) dictionary
+value range inside a contiguous key segment, found with rank-threshold
+scans or two vectorised binary searches (``pipeline._delta_bounds``) —
+O(T log T) at most, versus the O(T · |A_k|) dictionary
 walks of the C#/.Net NOAC implementation the paper benchmarks in §6.
 
 Set signatures of ranges come from per-mode prefix sums of

@@ -17,7 +17,8 @@ per-key *component operator* differs.  This module is that factoring:
            fallback runs behind the same API.
   comp-op  ``prime_components``     cumulus = the whole key segment.
            ``delta_components``     δ-range inside the key segment
-                                    (two vectorised binary searches).
+                                    (rank-threshold scans or two
+                                    vectorised binary searches).
            This is the only place the variants differ.
   Stage 2  ``mix_signatures``       gather per-mode ⟨signature,
            cardinality⟩ aggregates back to each generating tuple.
@@ -115,18 +116,21 @@ def segment_starts(sorted_key_cols: Sequence[jnp.ndarray]) -> jnp.ndarray:
 
 def segment_bounds(flags: jnp.ndarray):
     """Per sorted position: the [a, b) window of its own run, where
-    ``flags`` marks run starts (``flags[0]`` must be True).
+    ``flags`` marks run starts (``flags[..., 0]`` must be True); a
+    stack of flag rows ``(..., T)`` is scanned row by row.
 
     Two O(T) scans — a forward cummax and a reverse cummin — instead of
     the segment-id cumsum + ``segment_min``/``segment_sum`` scatter
     formulation, which dominates Stage-1 time on scatter-unfriendly
     backends."""
-    t = flags.shape[0]
+    t, axis = flags.shape[-1], flags.ndim - 1
     pos = jnp.arange(t, dtype=jnp.int32)
-    a = cumulative(jnp.where(flags, pos, 0), jax.lax.max)
+    a = cumulative(jnp.where(flags, pos, 0), jax.lax.max, axis=axis)
     suff = cumulative(jnp.where(flags, pos, jnp.int32(t)), jax.lax.min,
-                      reverse=True)
-    b = jnp.concatenate([suff[1:], jnp.full((1,), t, jnp.int32)])
+                      axis=axis, reverse=True)
+    b = jnp.concatenate([suff[..., 1:],
+                         jnp.full(flags.shape[:-1] + (1,), t, jnp.int32)],
+                        axis=axis)
     return a, b
 
 
@@ -322,9 +326,9 @@ def delta_components(sm: SortedMode, r_lo: jnp.ndarray, r_hi: jnp.ndarray,
                      ) -> ModeComponents:
     """δ-range operator (NOAC, §3.2/§4.3): the component of a tuple with
     value v0 is the contiguous value-window [v0-δ, v0+δ] *inside* its key
-    segment, found with two binary searches.  Signatures are differences
-    of the fused masked prefix sums (modular arithmetic makes range
-    differences exact)."""
+    segment, bounded by :func:`_delta_bounds`.  Signatures are
+    differences of the fused masked prefix sums (modular arithmetic
+    makes range differences exact)."""
     pref_lo, pref_hi, pref_cnt = masked_prefix(
         r_lo[sm.sorted_e], r_hi[sm.sorted_e], sm.first_occ, use_pallas)
     with jax.named_scope("delta_search"):
@@ -336,20 +340,38 @@ def delta_components(sm: SortedMode, r_lo: jnp.ndarray, r_hi: jnp.ndarray,
                           hi_idx.astype(jnp.int32))
 
 
+def delta_bounds_path(t: int, value_slots: Optional[int]) -> str:
+    """How :func:`_delta_bounds` finds the δ-windows of a ``t``-row
+    table whose value lane is rank-coded over ``value_slots`` domain
+    entries (None: no rank-coded lane).  ``"runs"``: one pair of
+    segment scans per rank threshold, taken when the D − 1 thresholds
+    sweep the table no more often than one binary search gathers from
+    it (``keys.search_steps``); ``"search"`` otherwise."""
+    if value_slots is not None and value_slots - 1 <= K.search_steps(t):
+        return "runs"
+    return "search"
+
+
 def _delta_bounds(sm: SortedMode, values: jnp.ndarray, delta: float,
                   value_domain: Optional[jnp.ndarray]):
     """Per tuple, in original order: the [lo, hi) window of sorted
     positions whose value lies within δ of the tuple's own, inside its
-    key segment."""
+    key segment.  A rank-coded lane over few values scans rank
+    thresholds (:func:`_rank_threshold_bounds`); otherwise two binary
+    searches find the bounds."""
     if sm.sorted_words is not None and sm.plan is not None \
             and sm.plan.with_values:
+        plan, d = sm.plan, jnp.float32(delta)
+        if plan.value_bits < 32 and delta_bounds_path(
+                sm.seg_a.shape[0], value_domain.shape[0]) == "runs":
+            lo, hi = _rank_threshold_bounds(sm, d, value_domain)
+            return lo[sm.inv], hi[sm.inv]
         # packed path: δ-window bounds by *global* search over the sorted
         # key words — the query key carries the tuple's own subrelation
         # prefix with the value lane set to v∓δ and e_k at its extreme,
         # so the search self-clamps to the segment and no per-query
         # window (or segment_bounds scan) is needed.  -0.0 targets are
         # normalised so word order agrees with float order.
-        plan, d = sm.plan, jnp.float32(delta)
         t_lo, t_hi = sm.sorted_vals - d, sm.sorted_vals + d
         if plan.value_bits == 32:
             t_lo = jnp.where(t_lo == 0, jnp.float32(0.0), t_lo)
@@ -379,6 +401,47 @@ def _delta_bounds(sm: SortedMode, values: jnp.ndarray, delta: float,
         hi_idx = bsearch(sm.sorted_vals, a, b, values + jnp.float32(delta),
                          leq=True)
     return lo_idx, hi_idx
+
+
+def _rank_threshold_bounds(sm: SortedMode, d: jnp.ndarray,
+                           value_domain: jnp.ndarray):
+    """δ-window bounds per *sorted* position of a rank-coded lane over
+    D domain values, by scans instead of searches.
+
+    Inside a key segment the ranks never decrease, so the positions of
+    rank ≥ t form a suffix of it, starting at ``A_t`` (the segment's end
+    if none).  The window of a tuple of rank r is [A_lo(r), A_hi(r)),
+    with lo(r) the least rank whose value is ≥ v_r − δ and hi(r) one
+    past the greatest whose value is ≤ v_r + δ — the ranks the search
+    path queries for, from the same float32 arithmetic, once per domain
+    entry.  Splitting each segment where the rank first reaches t gives
+    runs whose :func:`segment_bounds` hold ``A_t``: the run's start for
+    a position of rank ≥ t, its end for one below.  ``A_0`` is the
+    segment's start and ``A_D`` its end."""
+    dom = value_domain.astype(jnp.float32)
+    n, t = dom.shape[0], sm.seg_a.shape[0]
+    lo_of = jnp.searchsorted(dom, dom - d, side="left",
+                             method="compare_all").astype(jnp.int32)
+    hi_of = jnp.searchsorted(dom, dom + d, side="right",
+                             method="compare_all").astype(jnp.int32)
+    rank = RX.extract_digit(sm.sorted_words, sm.plan.e_bits,
+                            sm.plan.value_bits).astype(jnp.int32)
+    # each position's threshold pair, selected over the D ranks (a rank
+    # past the domain reads its last entry, as a clamped gather would)
+    lo_t, hi_t = lo_of[n - 1], hi_of[n - 1]
+    for r in range(n - 1):
+        lo_t = jnp.where(rank == r, lo_of[r], lo_t)
+        hi_t = jnp.where(rank == r, hi_of[r], hi_t)
+    lo, hi = sm.seg_a, sm.seg_b
+    if n > 1:
+        ths = jnp.arange(1, n, dtype=jnp.int32)[:, None]
+        prev = jnp.concatenate([rank[:1], rank[:-1]])
+        seg_start = sm.seg_a == jnp.arange(t, dtype=jnp.int32)
+        a, b = segment_bounds(seg_start | ((rank >= ths) & (prev < ths)))
+        for i in range(n - 1):
+            lo = jnp.where(lo_t == i + 1, a[i], lo)
+            hi = jnp.where(hi_t == i + 1, b[i], hi)
+    return lo, hi
 
 
 # ---------------------------------------------------------------------------
@@ -721,8 +784,29 @@ class PipelineMiner:
         tuples, values = _copy_in(obs, tuples, values)
         return self._dispatch(obs, tuples, values=values, value_domain=vdom)
 
+    def _rank_lane_slots(self, value_domain) -> Optional[int]:
+        """The domain size D when ``mine_tuples`` packs this mine's value
+        lane rank-coded (a domain, and a packed sort of the pruned
+        plans), else None — :func:`delta_bounds_path`'s argument."""
+        if value_domain is None:
+            return None
+        slots = int(value_domain.shape[0])
+        fits = K.plan_context_keys(self.sizes, True, slots)[0].fits
+        if RX.resolve_sort_backend(self.sort_backend, self.packed,
+                                   fits) == "lexsort":
+            return None
+        return slots
+
     def _dispatch(self, obs, tuples, **kw) -> PipelineResult:
-        """Launch the jitted pipeline; with a hub, wait for it."""
+        """Launch the jitted pipeline; with a hub, wait for it (and, on
+        a NOAC mine, count each mode's δ-window path in
+        ``pipeline_delta_bounds_total{path}``)."""
+        if obs is not None and self.delta is not None:
+            path = delta_bounds_path(
+                tuples.shape[0],
+                self._rank_lane_slots(kw.get("value_domain")))
+            obs.metrics.counter("pipeline_delta_bounds_total",
+                                path=path).inc(len(self.sizes))
         with phase("mine.dispatch", obs):
             res = self._fn(tuples, self._lo, self._hi, **kw)
         if obs is not None:

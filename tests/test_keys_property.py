@@ -37,7 +37,8 @@ def contexts(draw, max_arity=4, max_size=7, max_tuples=40,
         # finite, no -0.0/NaN: the documented domain of the
         # order-preserving float encoding (DESIGN.md §3a)
         vals = np.asarray(draw(st.lists(
-            st.floats(0.001, 1000.0, width=32), min_size=n, max_size=n)),
+            st.floats(float(np.float32(0.001)), 1000.0, width=32),
+            min_size=n, max_size=n)),
             np.float32)
     return PolyadicContext(sizes, np.asarray(rows, np.int32), vals)
 
@@ -106,7 +107,8 @@ def test_host_device_packers_bit_identical(ctx):
 
 
 @settings(max_examples=25, deadline=None)
-@given(st.lists(st.floats(-1e30, 1e30, width=32), min_size=2, max_size=50))
+@given(st.lists(st.floats(-float(np.float32(1e30)), float(np.float32(1e30)),
+                          width=32), min_size=2, max_size=50))
 def test_float_sort_bits_monotone_bijection(vals):
     v = np.asarray(vals, np.float32)
     v = np.where(v == 0, np.float32(0.0), v)    # normalise -0.0

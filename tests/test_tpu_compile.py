@@ -87,9 +87,11 @@ def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
     """Compiled for the chip with its Mosaic kernels, every operation of
     a mine that carries the program's ``op_name`` lies under a stage
     scope: the scans' fusions too, which ``lax.cum*`` would leave
-    unscoped (XLA's own reduce-window trees and copies carry none)."""
+    unscoped (XLA's own reduce-window trees and copies carry none).
+    The NOAC mine's δ-window bounds take the rank-threshold scans,
+    under ``stage2_components/delta_search``."""
     from repro.core import BatchMiner, NOACMiner
-    from repro.core.pipeline import STAGE_SCOPES
+    from repro.core.pipeline import STAGE_SCOPES, delta_bounds_path
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     t = 8192
 
@@ -99,6 +101,7 @@ def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
         m, kw = BatchMiner((2337, 67464, 28920), use_pallas=True), {}
     else:
         m = NOACMiner((6040, 3952, 5), delta=1.0, use_pallas=True)
+        assert delta_bounds_path(t, 5) == "runs"
         kw = {"values": sds((t,), jnp.float32),
               "value_domain": sds((5,), jnp.float32)}
     text = m._fn.lower(sds((t, 3), jnp.int32),
@@ -108,6 +111,7 @@ def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
     entry = text[text.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
     named = merged = 0
+    delta_ops = set()
     for line in entry.splitlines()[1:]:
         name = re.search(r'op_name="([^"]*)"', line)
         op = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([a-z][\w-]*)\(", line)
@@ -122,4 +126,11 @@ def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
         # trace gives it to the first stage of its path
         merged += stages > 1
         named += 1
+        if "/stage2_components/delta_search/" in name.group(1):
+            delta_ops.add(path[-1])
     assert named > 100 and merged <= 0.01 * named, (merged, named)
+    # the threshold scans (the searches run none) and the [sm.inv]
+    # gathers; a prime mine has no δ-windows
+    want = ({"reduce_window_max", "reduce_window_min", "gather"}
+            if variant == "noac" else set())
+    assert want <= delta_ops and bool(delta_ops) == bool(want), delta_ops
