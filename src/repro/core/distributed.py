@@ -51,6 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from .._compat import cumulative
+from ..obs.phases import phase
 from . import keys as K
 from . import pipeline as PL
 from . import radix as RX
@@ -120,7 +122,7 @@ def _range_partition(words, plan: K.ModeKeyPlan, axes, n_shards: int,
     hist = jnp.zeros((nb,), jnp.int32).at[dig.astype(jnp.int32)].add(1)
     hist = jax.lax.psum(hist, axes)
     cum_before = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(hist, dtype=jnp.int32)[:-1]])
+        [jnp.zeros((1,), jnp.int32), cumulative(hist, jax.lax.add)[:-1]])
     total = jnp.maximum(cum_before[-1] + hist[-1], 1)
     # boundary math in float32: cum*n_shards overflows int32 at scale,
     # and any digit->shard function is correct (owners sort their own
@@ -163,32 +165,14 @@ def _dispatch(records: jnp.ndarray, owner: jnp.ndarray, n_shards: int,
     return buf, valid, slot_safe, ok, overflow
 
 
-def _sorted_components(w_lo_raw, w_hi_raw, first_occ, seg_flag, s_vals,
-                       delta: Optional[float], use_pallas: bool):
-    """Per sorted position: (sig_lo, sig_hi, distinct) of the position's
-    component — the whole key segment (prime) or the δ-window inside it —
-    as boundary differences of the fused masked prefix sums (the same
-    reduction the single-shard pipeline runs)."""
-    pref_lo, pref_hi, pref_cnt = PL.masked_prefix(w_lo_raw, w_hi_raw,
-                                                  first_occ, use_pallas)
-    a, b = PL.segment_bounds(seg_flag)
-    if delta is not None:
-        lo_idx = PL.bsearch(s_vals, a, b, s_vals - jnp.float32(delta),
-                            leq=False)
-        hi_idx = PL.bsearch(s_vals, a, b, s_vals + jnp.float32(delta),
-                            leq=True)
-        a, b = lo_idx, hi_idx
-    return pref_lo[b] - pref_lo[a], pref_hi[b] - pref_hi[a], \
-        pref_cnt[b] - pref_cnt[a]
-
-
 def _owner_stage(recv: jnp.ndarray, rvalid: jnp.ndarray, n_other: int,
                  r_lo: jnp.ndarray, r_hi: jnp.ndarray,
                  delta: Optional[float], use_pallas: bool = False):
     """Owner-side Reduce-1 (column-record fallback): segment received
-    ⟨key, e[, value]⟩ records and run the variant's component operator,
-    producing per-record (set-signature, distinct cardinality,
-    tuple-first flag)."""
+    ⟨key, e[, value]⟩ records and run the variant's component operator
+    of ``core.pipeline`` (δ-windows by binary search inside each
+    segment), producing per-record (set-signature, distinct
+    cardinality, tuple-first flag)."""
     big = jnp.int32(np.iinfo(np.int32).max)
     key_cols = [jnp.where(rvalid, recv[:, j], big) for j in range(n_other)]
     e_col = jnp.where(rvalid, recv[:, n_other], big)
@@ -203,17 +187,18 @@ def _owner_stage(recv: jnp.ndarray, rvalid: jnp.ndarray, n_other: int,
     s_keys = [c[perm] for c in key_cols]
     s_e = e_col[perm]
     s_valid = rvalid[perm]
-    seg_flag = PL.segment_starts(s_keys)
     s_vals = vals[perm] if vals is not None else None
     first_occ = PL.segment_starts(
         s_keys + ([s_vals] if s_vals is not None else []) + [s_e]) & s_valid
-    e_safe = jnp.where(s_valid, s_e, 0)
-    sig_lo, sig_hi, distinct = _sorted_components(
-        r_lo[e_safe], r_hi[e_safe], first_occ, seg_flag, s_vals, delta,
-        use_pallas)
+    seg_a, seg_b = PL.segment_bounds(PL.segment_starts(s_keys))
     inv = jnp.zeros((l,), jnp.int32).at[perm].set(
         jnp.arange(l, dtype=jnp.int32))
-    return sig_lo[inv], sig_hi[inv], distinct[inv], first_occ[inv]
+    sm = PL.SortedMode(perm, inv, seg_a, seg_b, jnp.where(s_valid, s_e, 0),
+                       s_vals, first_occ)
+    comps = (PL.prime_components(sm, r_lo, r_hi, use_pallas)
+             if delta is None else
+             PL.delta_components(sm, r_lo, r_hi, vals, delta, use_pallas))
+    return comps.sig_lo, comps.sig_hi, comps.card, first_occ[inv]
 
 
 def _validity_words(words, inval: jnp.ndarray, total_bits: int):
@@ -234,36 +219,57 @@ def _owner_stage_packed(recv: jnp.ndarray, rvalid: jnp.ndarray,
                         value_domain=None):
     """Owner-side Reduce-1 over *pre-packed* key words: one stable sort
     keyed on (validity, key words) with the permutation carried as a
-    payload; entity ids and value columns are bit-field extractions from
-    the shipped key, so owners never re-pack.  The radix backend folds
-    the validity flag into the key as one extra MSB (falling back to
-    ``lax.sort`` for exactly-64-bit keys, where the flag has no room)."""
+    payload, then the variant's component operator of ``core.pipeline``
+    on the owner's ``SortedMode`` — δ-windows included, by the batch
+    path's ``_delta_bounds``.  Entity ids and value columns are
+    bit-field extractions from the shipped key, so owners never
+    re-pack.
+
+    The validity flag is folded into the key as its next bit (invalid
+    slots sort last), so the sorted words stay globally ordered and the
+    padding forms one segment of its own: no valid slot's segment, rank
+    runs or search ever reaches it.  A key of exactly 64 bits leaves the
+    flag no room; its sort carries the flag as a separate key and its
+    δ-windows are searched inside each segment."""
     l = recv.shape[0]
     words = tuple(recv[:, i] for i in range(recv.shape[1]))
     inval = (~rvalid).astype(jnp.uint32)   # invalid slots sort last
     iota = jnp.arange(l, dtype=jnp.int32)
-    if sort_backend == "radix" and plan.total_bits + 1 <= 64:
+    values = None
+    if plan.total_bits + 1 <= 64:
         ext = _validity_words(words, inval, plan.total_bits)
-        perm = RX.radix_sort_perm(ext, plan.total_bits + 1, use_pallas)
-        s_inval = inval[perm]
-        s_words = tuple(w[perm] for w in words)
-        s_valid = rvalid[perm]
+        if sort_backend == "radix":
+            perm = RX.radix_sort_perm(ext, plan.total_bits + 1, use_pallas)
+            s_words = tuple(w[perm] for w in ext)
+        else:
+            out = jax.lax.sort(ext + (iota,), num_keys=len(ext),
+                               is_stable=True)
+            s_words, perm = tuple(out[:-1]), out[-1]
+        s_valid = RX.extract_digit(s_words, plan.total_bits, 1) == 0
+        seg_words = K.drop_low_bits(s_words, plan.seg_shift)
+        occ_words, sm_words, sm_plan = s_words, s_words, plan
     else:
-        out = jax.lax.sort((inval,) + words + (rvalid, iota),
+        out = jax.lax.sort((inval,) + words + (iota,),
                            num_keys=1 + len(words), is_stable=True)
-        s_inval, s_words = out[0], tuple(out[1:1 + len(words)])
-        s_valid, perm = out[-2], out[-1]
-    seg_flag = PL.segment_starts(
-        [s_inval] + list(K.drop_low_bits(s_words, plan.seg_shift)))
-    first_occ = PL.segment_starts([s_inval] + list(s_words)) & s_valid
-    e_safe = jnp.where(s_valid, plan.extract_entity(s_words), 0)
+        s_inval, s_words, perm = out[0], tuple(out[1:-1]), out[-1]
+        s_valid = s_inval == 0
+        seg_words = (s_inval,) + K.drop_low_bits(s_words, plan.seg_shift)
+        occ_words, sm_words, sm_plan = (s_inval,) + s_words, None, None
+        if delta is not None:
+            values = plan.extract_values(words, domain=value_domain)
+    first_occ = PL.segment_starts(list(occ_words)) & s_valid
+    seg_a, seg_b = PL.segment_bounds(PL.segment_starts(list(seg_words)))
     s_vals = (plan.extract_values(s_words, domain=value_domain)
               if delta is not None else None)
-    sig_lo, sig_hi, distinct = _sorted_components(
-        r_lo[e_safe], r_hi[e_safe], first_occ, seg_flag, s_vals, delta,
-        use_pallas)
     inv = jnp.zeros((l,), jnp.int32).at[perm].set(iota)
-    return sig_lo[inv], sig_hi[inv], distinct[inv], first_occ[inv]
+    sm = PL.SortedMode(perm, inv, seg_a, seg_b,
+                       jnp.where(s_valid, plan.extract_entity(s_words), 0),
+                       s_vals, first_occ, sm_words, sm_plan)
+    comps = (PL.prime_components(sm, r_lo, r_hi, use_pallas)
+             if delta is None else
+             PL.delta_components(sm, r_lo, r_hi, values, delta, use_pallas,
+                                 value_domain=value_domain))
+    return comps.sig_lo, comps.sig_hi, comps.card, first_occ[inv]
 
 
 def _shuffle_mode(tuples, values, k, axes, n_shards, capacity, r_lo, r_hi,
@@ -277,40 +283,45 @@ def _shuffle_mode(tuples, values, k, axes, n_shards, capacity, r_lo, r_hi,
     radix top-digit histogram; otherwise the original column records,
     hash-partitioned."""
     n = tuples.shape[1]
-    others = [tuples[:, j] for j in range(n) if j != k]
-    hash_owner = (_hash_columns(others, 0xA11CE + k) %
-                  jnp.uint32(n_shards)).astype(jnp.int32)
-    if plan is not None and plan.fits:
-        words = plan.pack_device(tuples, values, domain=value_domain)
-        owner = (_range_partition(words, plan, axes, n_shards, capacity,
-                                  hash_owner)
-                 if sort_backend == "radix" else hash_owner)
-        records = jnp.stack(words, axis=1)
-    else:
-        plan = None
-        owner = hash_owner
-        cols = others + [tuples[:, k]]
-        if delta is not None:
-            cols = cols + [jax.lax.bitcast_convert_type(values, jnp.int32)]
-        records = jnp.stack(cols, axis=1)
-    buf, valid, slot, ok, overflow = _dispatch(records, owner, n_shards,
-                                               capacity)
-    recv = jax.lax.all_to_all(buf, axes, 0, 0, tiled=True)
-    rvalid = jax.lax.all_to_all(valid.astype(jnp.int32), axes, 0, 0,
-                                tiled=True).astype(bool)
-    if plan is not None:
-        sig_lo, sig_hi, card, tfirst = _owner_stage_packed(
-            recv, rvalid, plan, r_lo, r_hi, delta, use_pallas,
-            sort_backend, value_domain)
-    else:
-        sig_lo, sig_hi, card, tfirst = _owner_stage(
-            recv, rvalid, n - 1, r_lo, r_hi, delta, use_pallas)
-    resp = jnp.stack([sig_lo, sig_hi, card.astype(jnp.uint32),
-                      tfirst.astype(jnp.uint32)], axis=1)
-    resp = jax.lax.all_to_all(resp, axes, 0, 0, tiled=True)
-    got = resp[slot]   # (L, 4) in original record order (garbage if !ok)
-    return (got[:, 0], got[:, 1], got[:, 2].astype(jnp.int32),
-            got[:, 3].astype(bool), ok, overflow)
+    with jax.named_scope("shuffle_route"):
+        others = [tuples[:, j] for j in range(n) if j != k]
+        hash_owner = (_hash_columns(others, 0xA11CE + k) %
+                      jnp.uint32(n_shards)).astype(jnp.int32)
+        if plan is not None and plan.fits:
+            words = plan.pack_device(tuples, values, domain=value_domain)
+            owner = (_range_partition(words, plan, axes, n_shards,
+                                      capacity, hash_owner)
+                     if sort_backend == "radix" else hash_owner)
+            records = jnp.stack(words, axis=1)
+        else:
+            plan = None
+            owner = hash_owner
+            cols = others + [tuples[:, k]]
+            if delta is not None:
+                cols = cols + [jax.lax.bitcast_convert_type(values,
+                                                            jnp.int32)]
+            records = jnp.stack(cols, axis=1)
+        buf, valid, slot, ok, overflow = _dispatch(records, owner, n_shards,
+                                                   capacity)
+    with jax.named_scope("shuffle_exchange"):
+        recv = jax.lax.all_to_all(buf, axes, 0, 0, tiled=True)
+        rvalid = jax.lax.all_to_all(valid.astype(jnp.int32), axes, 0, 0,
+                                    tiled=True).astype(bool)
+    with jax.named_scope("shuffle_owner"):
+        if plan is not None:
+            sig_lo, sig_hi, card, tfirst = _owner_stage_packed(
+                recv, rvalid, plan, r_lo, r_hi, delta, use_pallas,
+                sort_backend, value_domain)
+        else:
+            sig_lo, sig_hi, card, tfirst = _owner_stage(
+                recv, rvalid, n - 1, r_lo, r_hi, delta, use_pallas)
+        resp = jnp.stack([sig_lo, sig_hi, card.astype(jnp.uint32),
+                          tfirst.astype(jnp.uint32)], axis=1)
+    with jax.named_scope("shuffle_exchange"):
+        resp = jax.lax.all_to_all(resp, axes, 0, 0, tiled=True)
+        got = resp[slot]   # (L, 4) in original record order (garbage if !ok)
+        return (got[:, 0], got[:, 1], got[:, 2].astype(jnp.int32),
+                got[:, 3].astype(bool), ok, overflow)
 
 
 # ---------------------------------------------------------------------------
@@ -335,6 +346,12 @@ class DistributedMiner:
       sort_backend: packed word-sort algorithm ('radix' default | 'lax';
         'lexsort' forces the column path).
       use_pallas: fused Pallas segment reductions (None: on TPU only).
+      obs: an optional ``repro.obs.Obs`` hub, as ``PipelineMiner``
+        takes: it times the host phases of ``__call__`` and counts
+        ``pipeline_delta_bounds_total{path}`` and, on the shuffle,
+        ``distributed_shuffle_records_total{mode}``,
+        ``distributed_shuffle_slots_total{mode}`` and
+        ``distributed_shuffle_retries_total``.
     """
 
     def __init__(self, sizes: Sequence[int], mesh, axes="data",
@@ -346,7 +363,9 @@ class DistributedMiner:
                  sort_backend: Optional[str] = None,
                  use_pallas: Optional[bool] = None,
                  prune_values: bool = True,
-                 window_budget: Optional[int] = None):
+                 window_budget: Optional[int] = None,
+                 obs=None):
+        self.obs = obs
         self.sizes = tuple(int(s) for s in sizes)
         self.prune_values = bool(prune_values)
         #: shared streaming unit (DESIGN.md §3c): windows the incremental
@@ -442,32 +461,42 @@ class DistributedMiner:
                              value_domain=vdom if vdom.shape[0] else None)
         return self._slice_block(res, tuples.shape[0])
 
-    def _body_shuffle(self, tuples, values, vdom, lo, hi):
-        axes, nsh = self.axes, self.n_shards
-        tl, n = tuples.shape
-        capacity = max(1, int(np.ceil(tl / nsh * self.capacity_factor)))
+    def _capacity(self, tl: int) -> int:
+        """Per-link dispatch capacity of a shard of ``tl`` rows."""
+        capacity = max(1, int(np.ceil(tl / self.n_shards
+                                      * self.capacity_factor)))
         if self.window_budget:
             # per-link batches ship in whole windows of the shared plan
             # (capacity only sizes the dispatch buffers / overflow check,
             # so rounding up never changes a mined bit)
             wb = int(self.window_budget)
             capacity = -(-capacity // wb) * wb
-        # rebuild the plans with the (replicated) value domain's slot
-        # count — vdom is empty when pruning is off, restoring the
-        # 32-bit float lane
+        return capacity
+
+    def _plans(self, value_slots: Optional[int]):
+        """(key plans, sort backend) of a mine whose value lane is
+        rank-coded over ``value_slots`` entries (None: the float lane).
+        The backend resolves from the PRUNED plans: a key that only fits
+        thanks to the rank-coded lane still takes the packed path."""
+        plans = K.plan_context_keys(self.sizes,
+                                    with_values=self.delta is not None,
+                                    value_slots=value_slots)
+        return plans, RX.resolve_sort_backend(self.sort_backend,
+                                              self.packed, plans[0].fits)
+
+    def _body_shuffle(self, tuples, values, vdom, lo, hi):
+        axes, nsh = self.axes, self.n_shards
+        tl, n = tuples.shape
+        capacity = self._capacity(tl)
+        # vdom is empty when pruning is off, restoring the 32-bit float
+        # lane
         vdom_opt = vdom if vdom.shape[0] else None
-        plans = K.plan_context_keys(
-            self.sizes, with_values=self.delta is not None,
-            value_slots=None if vdom_opt is None else vdom_opt.shape[0])
-        # resolve from the PRUNED plans: a key that only fits thanks to
-        # the rank-coded lane still takes the packed path
-        backend = RX.resolve_sort_backend(self.sort_backend, self.packed,
-                                          plans[0].fits)
+        plans, backend = self._plans(
+            None if vdom_opt is None else vdom_opt.shape[0])
         packed_active = backend != "lexsort"
         per_lo, per_hi, cards = [], [], []
         overflow = jnp.int32(0)
         tuple_first = None
-        ok_all = jnp.ones((tl,), bool)
         for k in range(n):
             slo, shi, card, tfirst, ok, ovf = _shuffle_mode(
                 tuples, values, k, axes, nsh, capacity, lo[k], hi[k],
@@ -479,39 +508,44 @@ class DistributedMiner:
             per_lo.append(slo)
             per_hi.append(shi)
             cards.append(card)
-            overflow = overflow + ovf.astype(jnp.int32)
-            ok_all = ok_all & ok
+            with jax.named_scope("shuffle_route"):
+                overflow = overflow + ovf.astype(jnp.int32)
             if k == 0:
                 tuple_first = tfirst
-        sig_lo, sig_hi = PL.mix_signatures(per_lo, per_hi)
-        volume = jnp.ones((tl,), jnp.float32)
-        for c in cards:
-            volume = volume * c.astype(jnp.float32)
-        # Stage 3 on gathered signatures (12 bytes/tuple on the wire).
-        g_lo = jax.lax.all_gather(sig_lo, axes, tiled=True)
-        g_hi = jax.lax.all_gather(sig_hi, axes, tiled=True)
-        g_tf = jax.lax.all_gather(tuple_first, axes, tiled=True)
-        s3_backend = RX.resolve_sort_backend(self.sort_backend, self.packed,
-                                             True)
-        gen_of, is_unique = PL.stage3_dedup(g_lo, g_hi, g_tf,
-                                            packed=s3_backend != "lexsort",
-                                            sort_backend=s3_backend,
-                                            use_pallas=self.use_pallas)
-        shard_id = jax.lax.axis_index(axes)
-        sl = jax.lax.dynamic_slice_in_dim
-        start = shard_id * tl
-        gen_l = sl(gen_of, start, tl)
-        uniq_l = sl(is_unique, start, tl)
-        density = PL.density_of(gen_l, volume)
-        keep = uniq_l & (density >= jnp.float32(self.theta))
-        if self.minsup:
+        with jax.named_scope("shuffle_route"):
+            overflow = jax.lax.psum(overflow, axes)
+        with jax.named_scope("stage2_mix"):
+            sig_lo, sig_hi = PL.mix_signatures(per_lo, per_hi)
+            volume = jnp.ones((tl,), jnp.float32)
             for c in cards:
-                keep = keep & (c >= self.minsup)
-        overflow = jax.lax.psum(overflow, axes)
+                volume = volume * c.astype(jnp.float32)
+            cardinalities = jnp.stack(cards)
+        # Stage 3 on gathered signatures (12 bytes/tuple on the wire).
+        with jax.named_scope("stage3_gather"):
+            g_lo = jax.lax.all_gather(sig_lo, axes, tiled=True)
+            g_hi = jax.lax.all_gather(sig_hi, axes, tiled=True)
+            g_tf = jax.lax.all_gather(tuple_first, axes, tiled=True)
+        with jax.named_scope("stage3_dedup"):
+            s3_backend = RX.resolve_sort_backend(self.sort_backend,
+                                                 self.packed, True)
+            gen_of, is_unique = PL.stage3_dedup(
+                g_lo, g_hi, g_tf, packed=s3_backend != "lexsort",
+                sort_backend=s3_backend, use_pallas=self.use_pallas)
+            shard_id = jax.lax.axis_index(axes)
+            sl = jax.lax.dynamic_slice_in_dim
+            start = shard_id * tl
+            gen_l = sl(gen_of, start, tl)
+            uniq_l = sl(is_unique, start, tl)
+            density = PL.density_of(gen_l, volume)
+            keep = uniq_l & (density >= jnp.float32(self.theta))
+            if self.minsup:
+                for c in cards:
+                    keep = keep & (c >= self.minsup)
+            n_clusters = is_unique.sum()
         return DistributedResult(
             sig_lo=sig_lo, sig_hi=sig_hi, is_unique=uniq_l, gen_count=gen_l,
             volume=volume, density=density, keep=keep,
-            cardinalities=jnp.stack(cards), n_clusters=is_unique.sum(),
+            cardinalities=cardinalities, n_clusters=n_clusters,
             overflow=overflow)
 
     def _body_replicate_perms(self, tuples, values, perms, lo, hi):
@@ -586,36 +620,90 @@ class DistributedMiner:
         with self.mesh:
             return fn.lower(*structs)
 
+    def _bounds_path(self, t: int, vdom) -> str:
+        """How this mine finds its δ-windows (``pipeline.delta_bounds_
+        path``): over each owner's receive buffer on the shuffle, over
+        the whole table on the replicate body."""
+        slots = int(vdom.shape[0]) or None
+        plans, backend = self._plans(slots)
+        if slots is None or backend == "lexsort":
+            return PL.delta_bounds_path(t, None)
+        if self.strategy == "shuffle":
+            if plans[0].total_bits + 1 > 64:
+                return "search"     # no room for the validity bit
+            t = self.n_shards * self._capacity(t // self.n_shards)
+        return PL.delta_bounds_path(t, slots)
+
+    def _launch(self, obs, tuples, values, vdom) -> DistributedResult:
+        """Dispatch the jitted program; with a hub, count what it will
+        do first (``DESIGN.md`` §11)."""
+        t = tuples.shape[0]
+        if obs is not None:
+            m, n = obs.metrics, len(self.sizes)
+            if self.delta is not None:
+                m.counter("pipeline_delta_bounds_total",
+                          path=self._bounds_path(t, vdom)).inc(n)
+            if self.strategy == "shuffle":
+                slots = self.n_shards * self.n_shards * self._capacity(
+                    t // self.n_shards)
+                for k in range(n):
+                    m.counter("distributed_shuffle_records_total",
+                              mode=str(k)).inc(t)
+                    m.counter("distributed_shuffle_slots_total",
+                              mode=str(k)).inc(slots)
+        with phase("mine.dispatch", obs):
+            return self._fn(tuples, values, vdom, self._lo, self._hi)
+
     def __call__(self, tuples, values=None) -> DistributedResult:
         """Run the pipeline. On shuffle-capacity overflow (the M/R skew
         failure mode the paper's §1 warns about) the capacity factor is
         doubled and the job re-executed — the analogue of Hadoop re-running
-        a failed reducer with more memory."""
-        tuples, values = self._coerce(tuples, values)
-        t = tuples.shape[0]
+        a failed reducer with more memory.
+
+        Host phases are ``repro.mine.*`` profiler spans under the batch
+        path's names: ``mine.value_domain`` (NOAC), ``mine.copy_in``,
+        ``mine.dispatch`` and, on the shuffle, ``mine.overflow_check``
+        (the wait for the result's dropped-record count)."""
+        obs = PL._active_obs(self.obs)
+        t = int(np.shape(tuples)[0])
         if t % self.n_shards:
             raise ValueError(
                 f"tuple count {t} not divisible by shard count "
                 f"{self.n_shards}; pad with duplicated rows (idempotent)")
+        if values is None:
+            values = np.zeros((t,), np.float32)
+        # the domain from the caller's (usually host-side) array, before
+        # the copy-in: np.unique never round-trips the device column
+        if self.delta is None:
+            vdom = self._value_domain(values)       # empty
+        else:
+            with phase("mine.value_domain", obs):
+                vdom = self._value_domain(values)
+        tuples, values = PL._copy_in(obs, tuples, values)
         if self._fn is None or self._t_global != t:
             self._fn = self._build(t)
             self._t_global = t
-        vdom = self._value_domain(values)
-        res = self._fn(tuples, values, vdom, self._lo, self._hi)
-        for _ in range(self.max_retries):
-            if self.strategy != "shuffle" or int(res.overflow) == 0:
+        res = self._launch(obs, tuples, values, vdom)
+        retries = 0
+        while self.strategy == "shuffle":
+            with phase("mine.overflow_check", obs):
+                dropped = int(res.overflow)
+            if dropped == 0:
                 break
+            if retries == self.max_retries:
+                # overflowed records were dropped by _dispatch —
+                # returning would hand back silently-wrong clusters
+                raise RuntimeError(
+                    f"shuffle capacity overflow persists after "
+                    f"{self.max_retries} retries (capacity_factor="
+                    f"{self.capacity_factor}); the partition is too "
+                    f"skewed for n_shards={self.n_shards}")
+            retries += 1
+            if obs is not None:
+                obs.metrics.counter("distributed_shuffle_retries_total").inc()
             self.capacity_factor *= 2.0
             self._fn = self._build(t)
-            res = self._fn(tuples, values, vdom, self._lo, self._hi)
-        if self.strategy == "shuffle" and int(res.overflow):
-            # overflowed records were dropped by _dispatch — returning
-            # would hand back silently-wrong clusters
-            raise RuntimeError(
-                f"shuffle capacity overflow persists after "
-                f"{self.max_retries} retries (capacity_factor="
-                f"{self.capacity_factor}); the partition is too skewed "
-                f"for n_shards={self.n_shards}")
+            res = self._launch(obs, tuples, values, vdom)
         return res
 
     # -- incremental snapshots (per-shard run stores, DESIGN.md §4) ---------

@@ -91,7 +91,9 @@ def mine(ctx: PolyadicContext, backend: str = "batch",
     auto, False = lexsort baseline), ``sort_backend`` ('radix' — the
     bit-plan-pruned LSD default — | 'lax' | 'lexsort'), ``use_pallas``
     (fused Pallas kernels; None = on TPU only).  Backend-specific:
-    ``mesh``/``axes``/``strategy``/``capacity_factor`` (distributed),
+    ``mesh``/``axes``/``strategy``/``capacity_factor``/``obs``
+    (distributed; ``obs`` an enabled ``repro.obs.Obs`` hub that times
+    and counts the mine, DESIGN.md §11),
     ``chunks``/``incremental`` (streaming; ``incremental=True`` on the
     distributed backend switches it to chunked ingestion + merged
     per-shard-run snapshots), ``chunk_budget`` (batch: out-of-core
@@ -212,7 +214,8 @@ def _run_distributed(ctx, p, values, **variant_kw):
         ctx.sizes, mesh, axes=p.get("axes", "data"),
         strategy=p.get("strategy", "replicate"),
         capacity_factor=p.get("capacity_factor", 2.0),
-        seed=p.get("seed", 0x5EED), **_pipe_kw(p), **variant_kw)
+        seed=p.get("seed", 0x5EED), obs=p.get("obs"), **_pipe_kw(p),
+        **variant_kw)
     if p.get("incremental"):
         # chunked ingestion + merged per-shard-run snapshot (core.runs)
         step = -(-ctx.num_tuples // max(1, int(p.get("chunks", 8))))

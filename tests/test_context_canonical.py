@@ -61,3 +61,26 @@ def test_table5_noac_seq_vs_par_parity():
     miner = NOACMiner(full.sizes, delta=100.0, rho_min=0.5, minsup=0)
     par = int(np.asarray(miner(sub.tuples, sub.values).keep).sum())
     assert len(seq) == par
+
+
+def test_packed_row_keys_canonicalise_as_rows_do():
+    """The one-word row keys find the same duplicates as a row-wise
+    ``np.unique``: one row per tuple in first-occurrence order, the last
+    value winning; a table too wide to pack takes the row-wise path."""
+    rng = np.random.default_rng(5)
+    for sizes in ((7, 5, 3), (2**31 - 1, 2**31 - 1, 4)):
+        rows = rng.integers(0, [4, 4, 3], (400, 3)).astype(np.int32)
+        rows[:, 0] *= (sizes[0] - 1) // 3
+        vals = rng.random(400).astype(np.float32)
+        ctx = PolyadicContext(sizes, rows, vals)
+        uniq, first, inv = np.unique(rows, axis=0, return_index=True,
+                                     return_inverse=True)
+        last = np.empty(uniq.shape[0], np.intp)
+        last[inv.ravel()] = np.arange(len(rows))
+        order = np.argsort(first, kind="stable")
+        np.testing.assert_array_equal(ctx.tuples, uniq[order])
+        np.testing.assert_array_equal(ctx.values, vals[last][order])
+    distinct = np.arange(30, dtype=np.int32).reshape(10, 3)
+    ctx = PolyadicContext((30, 30, 30), distinct,
+                          np.ones(10, np.float32))
+    np.testing.assert_array_equal(ctx.tuples, distinct)

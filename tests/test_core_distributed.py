@@ -52,3 +52,65 @@ def test_padding_is_idempotent():
     a, b = bm(ctx.tuples), bm(padded)
     assert int(np.asarray(a.is_unique).sum()) == int(
         np.asarray(b.is_unique).sum())
+
+
+def _series(obs, name, field="value") -> dict:
+    doc = obs.metrics.to_dict().get(name, {"series": []})
+    return {tuple(sorted(r["labels"].items())): r[field]
+            for r in doc["series"]}
+
+
+def test_shuffle_counts_and_phases_with_a_hub():
+    """An enabled hub counts the records routed and the owner slots
+    sorted per mode, the overflow retry, the δ-window path of each
+    owner stage, and times the host phases under the batch path's
+    names; a retried mine is still the batch answer."""
+    from repro.core import NOACMiner
+    from repro.obs import Obs
+    mesh = make_mesh((1,), ("data",))
+    ctx = synthetic.random_context((8, 6, 5), 96, seed=2,
+                                   values=True).deduplicated()
+    t = ctx.num_tuples
+    obs = Obs.create()
+    dm = DistributedMiner(ctx.sizes, mesh, axes="data", strategy="shuffle",
+                          delta=1.0, capacity_factor=0.5, obs=obs)
+    got = dm(ctx.tuples, ctx.values)
+    want = NOACMiner(ctx.sizes, delta=1.0)(ctx.tuples, ctx.values)
+    for f in ("sig_lo", "sig_hi", "keep", "density"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(want, f)))
+    assert dm.capacity_factor == 1.0
+    assert _series(obs, "distributed_shuffle_retries_total") == {(): 1}
+    slots = -(-t // 2) + t
+    for k in range(3):
+        mode = (("mode", str(k)),)
+        assert _series(obs, "distributed_shuffle_records_total")[mode] \
+            == 2 * t
+        assert _series(obs, "distributed_shuffle_slots_total")[mode] \
+            == slots
+    paths = _series(obs, "pipeline_delta_bounds_total")
+    assert sum(paths.values()) == 6 and len(paths) == 1
+    stages = {dict(k)["stage"]: v for k, v in
+              _series(obs, "pipeline_stage_ms", "count").items()}
+    assert stages == {"mine.value_domain": 1, "mine.copy_in": 1,
+                      "mine.dispatch": 2, "mine.overflow_check": 2}
+
+
+def test_value_domain_read_from_the_host_array(monkeypatch):
+    """The NOAC value domain comes from the caller's array before the
+    copy-in: ``value_domain_host`` never sees a device array."""
+    from repro.core import keys as K
+    seen = []
+    real = K.value_domain_host
+
+    def spy(values):
+        seen.append(type(values))
+        return real(values)
+    monkeypatch.setattr(K, "value_domain_host", spy)
+    mesh = make_mesh((1,), ("data",))
+    ctx = synthetic.random_context((8, 6, 5), 64, seed=3,
+                                   values=True).deduplicated()
+    dm = DistributedMiner(ctx.sizes, mesh, axes="data", strategy="shuffle",
+                          delta=1.0)
+    dm(ctx.tuples, ctx.values)
+    assert seen == [np.ndarray]

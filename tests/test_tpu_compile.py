@@ -134,3 +134,63 @@ def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
     want = ({"reduce_window_max", "reduce_window_min", "gather"}
             if variant == "noac" else set())
     assert want <= delta_ops and bool(delta_ops) == bool(want), delta_ops
+
+
+def test_shuffle_compiles_for_a_v5e_host(topo, monkeypatch):
+    """The NOAC shuffle of a two-word key over D = 10 half stars,
+    compiled for the four chips of a described v5e host: the records
+    cross the chips by all-to-all (out and back, per mode), every
+    operation that carries the program's ``op_name`` lies under one of
+    the shuffle's stage scopes, and each owner stage's δ-windows are
+    the rank-threshold scans — no search loop under ``delta_search``."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import DistributedMiner
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        mesh = Mesh(np.asarray(topo.devices), ("data",))
+        m = DistributedMiner((162541, 62423, 10), mesh, strategy="shuffle",
+                             delta=1.0, use_pallas=True)
+        t = 16384
+
+        def sds(shape, dtype, spec):
+            return jax.ShapeDtypeStruct(shape, dtype,
+                                        sharding=NamedSharding(mesh, spec))
+        text = m._build(t).lower(
+            sds((t, 3), jnp.int32, P("data", None)),
+            sds((t,), jnp.float32, P("data")),
+            sds((10,), jnp.float32, P()),
+            [sds(h.shape, h.dtype, P()) for h in m._lo],
+            [sds(h.shape, h.dtype, P()) for h in m._hi]).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    stages = ("shuffle_route", "shuffle_exchange", "shuffle_owner",
+              "stage2_mix", "stage3_gather", "stage3_dedup")
+    assert len(re.findall(r" all-to-all\(", text)) == 9
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    named, delta_ops = 0, set()
+    for line in entry.splitlines()[1:]:
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([a-z][\w-]*)\(", line)
+        if name is None or op is None or op.group(1) in (
+                "parameter", "constant", "tuple", "get-tuple-element",
+                "copy"):
+            continue
+        path = name.group(1).split("/")
+        if not any(s in path for s in stages):
+            # only XLA's own instructions (reduce-window trees of the
+            # scans, their slices) inherit the bare shard_map path
+            assert re.search(r"-|\.\d+$", path[-1]), line[:300]
+            continue
+        named += 1
+        if "delta_search" in path:
+            delta_ops.add(path[-1])
+    assert named > 100
+    assert {"reduce_window_max", "reduce_window_min"} <= delta_ops
+    assert "while" not in delta_ops, delta_ops
