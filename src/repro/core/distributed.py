@@ -146,21 +146,28 @@ def _range_partition(words, plan: K.ModeKeyPlan, axes, n_shards: int,
 def _dispatch(records: jnp.ndarray, owner: jnp.ndarray, n_shards: int,
               capacity: int):
     """Pack ``records`` (L, W) into a (n_shards*capacity, W) send buffer by
-    owner shard, plus validity mask, slot handle per record and overflow."""
-    l = records.shape[0]
-    # position of each record within its owner's group
-    order = jnp.argsort(owner, stable=True)
-    sorted_owner = owner[order]
-    pos_in_group = jnp.arange(l) - jnp.searchsorted(sorted_owner, sorted_owner,
-                                                    side="left")
-    rank = jnp.zeros((l,), jnp.int32).at[order].set(pos_in_group.astype(jnp.int32))
+    owner shard, plus validity mask, slot handle per record and overflow.
+
+    A record's rank in its owner's range is the number of earlier
+    records with the same owner, read from per-owner running counts:
+    one inclusive scan of the (n_shards, L) one-hot owner matrix, with
+    no sort or search.  The owners' totals (the scan's last column)
+    give which slots are filled, so validity needs no scatter."""
+    shards = jnp.arange(n_shards, dtype=owner.dtype)
+    hot = (owner[None, :] == shards[:, None]).astype(jnp.int32)
+    count = cumulative(hot, jax.lax.add, axis=1)
+    rank = (hot * count).sum(axis=0) - 1
     ok = rank < capacity
     nslots = n_shards * capacity
     # overflowed records go to a trash slot one past the end
     slot_safe = jnp.where(ok, owner * capacity + rank, nslots)
     buf = jnp.zeros((nslots + 1, records.shape[1]), records.dtype)
     buf = buf.at[slot_safe].set(records)[:nslots]
-    valid = jnp.zeros((nslots + 1,), bool).at[slot_safe].set(ok)[:nslots]
+    # slot i is filled iff its place in its owner's range is below that
+    # owner's total; on the flat index, since a reshape of an
+    # (n_shards, capacity) mask costs a relayout
+    i = jnp.arange(nslots, dtype=jnp.int32)
+    valid = i % capacity < count[:, -1][i // capacity]
     overflow = (~ok).sum()
     return buf, valid, slot_safe, ok, overflow
 

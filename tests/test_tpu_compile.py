@@ -136,13 +136,41 @@ def test_mine_ops_keep_their_stage_scope_on_v5e(one_chip, monkeypatch,
     assert want <= delta_ops and bool(delta_ops) == bool(want), delta_ops
 
 
+def _callers(text: str) -> dict:
+    """Stack frame id → the Python functions of the frame, innermost
+    first, from a compiled module's ``FunctionNames``, ``FileLocations``
+    and ``StackFrames`` tables (a frame's ``parent_frame_id`` is the
+    parent's id plus one; 0 is none)."""
+    def table(title, pattern):
+        body = text[text.index(f"\n{title}\n"):].split("\n\n")[0]
+        return {int(m.group(1)): m.groups()[1:]
+                for m in re.finditer(pattern, body)}
+    fns = table("FunctionNames", r'\n(\d+) "([^"]*)"')
+    locs = table("FileLocations", r"\n(\d+) \{.*?function_name_id=(\d+)")
+    frames = table("StackFrames",
+                   r"\n(\d+) \{file_location_id=(\d+) parent_frame_id=(\d+)")
+    out = {}
+    for f in frames:
+        chain, g = [], f
+        while g in frames and len(chain) < len(frames):
+            loc, parent = frames[g]
+            chain.append(fns[int(locs[int(loc)][0])][0])
+            g = int(parent) - 1
+        out[f] = chain
+    return out
+
+
 def test_shuffle_compiles_for_a_v5e_host(topo, monkeypatch):
     """The NOAC shuffle of a two-word key over D = 10 half stars,
     compiled for the four chips of a described v5e host: the records
     cross the chips by all-to-all (out and back, per mode), every
     operation that carries the program's ``op_name`` lies under one of
     the shuffle's stage scopes, and each owner stage's δ-windows are
-    the rank-threshold scans — no search loop under ``delta_search``."""
+    the rank-threshold scans — no search loop under ``delta_search``.
+    The dispatch ranks records by per-owner running counts: the one
+    loop under ``shuffle_route`` is the value lane's search of the
+    D-value domain (``ModeKeyPlan.value_lane``, as on the batch path),
+    and nothing there sorts."""
     import numpy as np
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -172,6 +200,16 @@ def test_shuffle_compiles_for_a_v5e_host(topo, monkeypatch):
     stages = ("shuffle_route", "shuffle_exchange", "shuffle_owner",
               "stage2_mix", "stage3_gather", "stage3_dedup")
     assert len(re.findall(r" all-to-all\(", text)) == 9
+    callers, route_loops = _callers(text), []
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%\S+ = .*? (while|sort)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if op and name and "shuffle_route" in name.group(1).split("/"):
+            frame = re.search(r"stack_frame_id=(\d+)", line)
+            fns = callers.get(int(frame.group(1)), []) if frame else []
+            route_loops.append((op.group(1), fns[:1]))
+    assert all(loop == ("while", ["ModeKeyPlan.value_lane"])
+               for loop in route_loops), route_loops
     entry = text[text.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
     named, delta_ops = 0, set()
