@@ -1,6 +1,6 @@
 """The jax calls shared across the repository, with the arguments it
 always passes (single home): the two every mesh user makes (DESIGN.md
-§6), and the cumulative scans of the mining pipeline."""
+§7), and the cumulative scans of the mining pipeline."""
 from __future__ import annotations
 
 import jax
@@ -16,7 +16,7 @@ def shard_map(f, mesh, in_specs, out_specs):
 
 def make_mesh(shape, names):
     """``jax.make_mesh`` with every axis of type Auto (GSPMD-propagated
-    shardings, as ``shard_map`` and the model layers expect)."""
+    shardings, as ``shard_map`` expects)."""
     return jax.make_mesh(shape, names,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 

@@ -1,9 +1,7 @@
-"""Compiled-artifact analysis: HLO collective parsing + roofline terms."""
+"""Compiled-artifact analysis: scan-aware HLO collective and traffic
+profiling."""
 from .hlo import (CollectiveStats, HLOProfile, parse_collectives,
                   profile_module)
-from .roofline import (HW, RooflineReport, model_flops, roofline_from_compiled,
-                       roofline_report)
 
 __all__ = ["CollectiveStats", "HLOProfile", "parse_collectives",
-           "profile_module", "HW", "RooflineReport", "model_flops",
-           "roofline_from_compiled", "roofline_report"]
+           "profile_module"]

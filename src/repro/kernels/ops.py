@@ -2,10 +2,7 @@
 
 Every public op here has the same calling convention as a plain jnp
 function, pads ragged inputs up to the kernel's block grid, and exposes
-``use_pallas=False`` fall-through to the pure-jnp oracle in ref.py. The
-model layers call these ops; with ``use_pallas=False`` (default in
-configs) the dry-run sees real XLA FLOPs (custom-call kernels are opaque
-to ``cost_analysis`` — DESIGN.md §7).
+``use_pallas=False`` fall-through to the pure-jnp oracle in ref.py.
 
 Whether a kernel is interpreted is decided in one place,
 :func:`_interpret`: the kernel body runs as interpreted HLO exactly when
@@ -19,14 +16,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import ref
-from .decode_attention import decode_attention as _decode_kernel
-from .flash_attention import flash_attention as _flash_kernel
 from .radix_sort import radix_histogram as _radix_histogram_kernel
 from .radix_sort import radix_rank as _radix_rank_kernel
-from .rmsnorm import rmsnorm as _rmsnorm_kernel
 from .segment_reduce import LANES
 from .segment_reduce import segment_reduce as _segment_reduce_kernel
 from .signature import signature as _signature_kernel
@@ -69,86 +62,6 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
 
-
-# ---------------------------------------------------------------------------
-# Attention
-# ---------------------------------------------------------------------------
-
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, window: Optional[int] = None,
-                    q_offset: Optional[int] = None,
-                    scale: Optional[float] = None,
-                    bq: int = 128, bk: int = 128,
-                    use_pallas: bool = True,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Batched GQA attention. q (B, Hq, Sq, D); k, v (B, Hkv, Skv, D)."""
-    if not use_pallas:
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                       q_offset=q_offset, scale=scale)
-    b, hq, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    group = hq // hkv
-    kv_len = skv
-    if q_offset is None:
-        q_offset = skv - sq
-    bq_ = min(bq, max(8, sq))
-    qp = _pad_to(q.reshape(b * hq, sq, d), 1, bq_)
-    kp = _pad_to(k.reshape(b * hkv, skv, d), 1, bk)
-    vp = _pad_to(v.reshape(b * hkv, skv, d), 1, bk)
-    out = _flash_kernel(qp, kp, vp, group=group, causal=causal,
-                        window=window, q_offset=q_offset, kv_len=kv_len,
-                        scale=scale, bq=bq_, bk=bk,
-                        interpret=_interpret(interpret))
-    return out[:, :sq].reshape(b, hq, sq, d)
-
-
-def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                     window: Optional[int] = None,
-                     kv_len: Optional[int] = None,
-                     scale: Optional[float] = None, bk: int = 512,
-                     use_pallas: bool = True,
-                     interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Single-token decode. q (B, Hq, D); k, v (B, Hkv, S, D)."""
-    if not use_pallas:
-        return ref.decode_attention_ref(q, k, v, window=window,
-                                        kv_len=kv_len, scale=scale)
-    b, hq, d = q.shape
-    _, hkv, s, _ = k.shape
-    group = hq // hkv
-    if kv_len is None:
-        kv_len = s
-    bk_ = min(bk, s)
-    kp = _pad_to(k.reshape(b * hkv, s, d), 1, bk_)
-    vp = _pad_to(v.reshape(b * hkv, s, d), 1, bk_)
-    out = _decode_kernel(q.reshape(b * hq, 1, d), kp, vp, group=group,
-                         window=window, kv_len=kv_len, scale=scale, bk=bk_,
-                         interpret=_interpret(interpret))
-    return out.reshape(b, hq, d)
-
-
-# ---------------------------------------------------------------------------
-# Norm
-# ---------------------------------------------------------------------------
-
-def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6, *,
-            use_pallas: bool = True,
-            interpret: Optional[bool] = None) -> jnp.ndarray:
-    """RMSNorm over the last axis; any leading shape."""
-    if not use_pallas:
-        return ref.rmsnorm_ref(x, w, eps)
-    lead = x.shape[:-1]
-    d = x.shape[-1]
-    rows = int(np.prod(lead)) if lead else 1
-    x2 = x.reshape(rows, d)
-    br = min(256, rows) if rows % min(256, rows) == 0 else 1
-    out = _rmsnorm_kernel(_pad_to(x2, 0, br), w, eps=eps, br=br,
-                          interpret=_interpret(interpret))
-    return out[:rows].reshape(*lead, d)
-
-
-# ---------------------------------------------------------------------------
-# Triclustering kernels (Stages 2/3 of the paper's pipeline)
-# ---------------------------------------------------------------------------
 
 def segment_reduce(w_lo: jnp.ndarray, w_hi: jnp.ndarray, first: jnp.ndarray,
                    *, bt: int = 64 * TILE, use_pallas: bool = True,

@@ -5,8 +5,6 @@ Each function is the semantic ground truth the kernel tests
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -75,66 +73,3 @@ def radix_rank_ref(digits: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
     oh = oh.astype(jnp.int32)
     occ = cumulative(oh, jax.lax.add, axis=0) - oh
     return (oh * (occ + starts[None, :])).sum(axis=1)
-
-
-def _attn_mask(sq: int, skv: int, q_offset: int, causal: bool,
-               window: Optional[int]) -> jnp.ndarray:
-    qpos = jnp.arange(sq)[:, None] + q_offset
-    kpos = jnp.arange(skv)[None, :]
-    mask = jnp.ones((sq, skv), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
-    return mask
-
-
-def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                        *, causal: bool = True,
-                        window: Optional[int] = None,
-                        q_offset: Optional[int] = None,
-                        scale: Optional[float] = None) -> jnp.ndarray:
-    """Reference attention. q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D);
-    GQA via head-group broadcast. fp32 softmax accumulation."""
-    b, hq, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    group = hq // hkv
-    if q_offset is None:
-        q_offset = skv - sq
-    if scale is None:
-        scale = d ** -0.5
-    qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    qf = qf.reshape(b, hkv, group, sq, d)
-    logits = jnp.einsum("bhgqd,bhkd->bhgqk", qf, kf)
-    mask = _attn_mask(sq, skv, q_offset, causal, window)
-    logits = jnp.where(mask[None, None, None], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, vf)
-    return out.reshape(b, hq, sq, d).astype(q.dtype)
-
-
-def decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                         *, window: Optional[int] = None,
-                         kv_len: Optional[int] = None,
-                         scale: Optional[float] = None) -> jnp.ndarray:
-    """Single-token decode. q: (B, Hq, D); k, v: (B, Hkv, S, D). The query
-    position is kv_len-1 (attends to keys [max(0, kv_len-window), kv_len))."""
-    b, hq, d = q.shape
-    _, hkv, s, _ = k.shape
-    if kv_len is None:
-        kv_len = s
-    out = flash_attention_ref(q[:, :, None, :], k, v, causal=True,
-                              window=window, q_offset=kv_len - 1,
-                              scale=scale)
-    return out[:, :, 0, :]
-
-
-def rmsnorm_ref(x: jnp.ndarray, w: jnp.ndarray,
-                eps: float = 1e-6) -> jnp.ndarray:
-    """RMSNorm rows of x (..., D) with fp32 statistics."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(
-        x.dtype)

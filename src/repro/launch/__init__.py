@@ -1,5 +1,2 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve/tricluster
-drivers. ``dryrun.py`` must be started as a fresh process (it forces 512
-host devices before importing jax); the other drivers run on whatever
-devices exist.
-"""
+"""Launchers: the tricluster and cluster-serving drivers, the local
+mesh and the compile cache they share."""

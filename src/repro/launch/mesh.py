@@ -1,8 +1,7 @@
-"""Production mesh construction (brief §MULTI-POD DRY-RUN).
+"""The local mesh the engines and drivers run on.
 
-``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state; the dry-run process forces 512
-host devices *before* any jax import and then calls it.
+``make_local_mesh`` is a FUNCTION (not a module constant) so importing
+this module never touches jax device state.
 """
 from __future__ import annotations
 
@@ -11,24 +10,7 @@ import jax
 from .._compat import make_mesh  # noqa: F401  (re-export; single shim home)
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_local_mesh(model: int = 1, pod: int = 0):
+def make_local_mesh():
     """Mesh over whatever devices exist (tests, examples, local runs):
-    (data=n/model, model) or (pod, data, model) when pod>0."""
-    n = len(jax.devices())
-    if pod:
-        shape = (pod, n // (pod * model), model)
-        axes = ("pod", "data", "model")
-    else:
-        shape = (n // model, model)
-        axes = ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def mesh_name(mesh) -> str:
-    return "x".join(str(s) for s in mesh.devices.shape)
+    (data=n, model=1)."""
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))

@@ -5,12 +5,7 @@ delta maintenance (``serve.clusters``), the stdlib HTTP
 endpoint/client (``serve.protocol``), zero-copy shared-memory snapshot
 replicas (``serve.shm``), the sharded query router (``serve.router``),
 the fault-tolerance layer — deterministic fault injection
-(``serve.faults``) and process supervision (``serve.supervise``) —
-plus the LM-side batched prefill+decode engine (``serve.engine``).
-
-``serve.engine`` is the only jax-dependent module here, so it is
-imported lazily: replica readers and routers import ``repro.serve``
-without paying (or needing) the accelerator stack.
+(``serve.faults``) and process supervision (``serve.supervise``).
 """
 from .clusters import (ClusterIndex, ClusterView, cluster_query,
                        pack_sig_words)
@@ -49,17 +44,4 @@ __all__ = [
     # fault tolerance: injection + supervision
     "FaultPlan", "FaultInjector", "Fault", "DropRequest",
     "KILL_EXIT_CODE", "Supervisor", "write_restart_flag",
-    # LM serving engine (lazy: jax)
-    "ServeEngine", "GenerationResult",
 ]
-
-_LAZY = {"ServeEngine": "engine", "GenerationResult": "engine"}
-
-
-def __getattr__(name: str):
-    mod = _LAZY.get(name)
-    if mod is None:
-        raise AttributeError(f"module {__name__!r} has no attribute "
-                             f"{name!r}")
-    from importlib import import_module
-    return getattr(import_module(f".{mod}", __name__), name)

@@ -3,24 +3,30 @@ compare against the single-device batch/NOAC engines — prime and NOAC
 variants, both merge strategies, bit-identical signatures and the same
 kept tuples; then NOAC shuffles on 4 devices whose owners take the
 shared δ-window bounds (rank-threshold runs over a two-word key, and a
-64-bit key's in-segment search), checked against the paper oracle too.
-Invoked by test_core_distributed.py; prints 'OK' on success."""
+64-bit key's in-segment search), checked against the paper oracle too;
+then the engine-conformance contexts (``_conformance.py``) under both
+strategies on 4 devices.
+
+Run by ``test_core_distributed.py`` (one case per entry of ``CHECKS``),
+or by hand: ``PYTHONPATH=src python tests/_distributed_check.py``.
+Prints one JSON verdict line per check, then 'OK' when all passed."""
+import json
 import os
 import sys
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import traceback
 
 import numpy as np
 import jax
 
+import _conformance as C
 from repro.core import (BatchMiner, DistributedMiner, NOACMiner,
-                        PolyadicContext, pad_tuples, pad_values)
+                        pad_tuples, pad_values)
 from repro.core import keys as K
 from repro.core import reference as R
+from repro.core.postprocess import cluster_set
 from repro.data import synthetic
 from repro.launch.mesh import make_mesh
 from repro.obs import Obs
-
 
 def _compare(got, want):
     assert int(got.overflow) == 0, f"overflow={int(got.overflow)}"
@@ -62,24 +68,6 @@ def check_noac(mesh, axes, strategy, sizes, t, delta, rho_min, minsup, seed):
     _compare(dm(tuples, values), want)
 
 
-HALF_STARS = np.arange(1, 11, dtype=np.float32) / 2     # 0.5 .. 5.0
-
-
-def rated_context(sizes, t, seed, values):
-    """``t`` draws of (user, movie, tag) from 40 x 30 ids spread over
-    the modes' whole id ranges and ``sizes[2]`` tags, each with a value
-    drawn apart from its ids, so key segments hold several rows and
-    δ-windows split them (the constructor keeps one row per tuple)."""
-    rng = np.random.default_rng(seed)
-    users = rng.choice(sizes[0], 40, replace=False)
-    movies = rng.choice(sizes[1], 30, replace=False)
-    rows = np.stack([users[rng.integers(0, 40, t)],
-                     movies[rng.integers(0, 30, t)],
-                     rng.integers(0, sizes[2], t)], 1)
-    return PolyadicContext(sizes, rows, values[rng.integers(0, len(values),
-                                                            t)])
-
-
 def bounds_paths(obs) -> dict:
     doc = obs.metrics.to_dict().get("pipeline_delta_bounds_total",
                                     {"series": []})
@@ -91,7 +79,7 @@ def check_owner_bounds(mesh, strategy, sizes, values, delta, seed, path,
     """Bit-identity with ``NOACMiner``, the kept clusters of the paper
     oracle, and the δ-window path every owner (or the replicated
     pipeline) counts: one per mode."""
-    ctx = rated_context(sizes, 3000, seed, values)
+    ctx = C.rated_context(sizes, 3000, seed, values)
     tuples = pad_tuples(ctx.tuples, 4)
     vals = pad_values(ctx.values, 4)
     nm = NOACMiner(sizes, delta=delta, **kw)
@@ -115,39 +103,100 @@ def check_owner_bounds(mesh, strategy, sizes, values, delta, seed, path,
     assert int(np.asarray(got.keep).sum()) == len(oracle)
 
 
+def check_conformance(mesh, strategy, name):
+    """A conformance context through ``strategy`` on ``mesh``:
+    bit-identical to the one-device engine, which keeps exactly the
+    paper oracle's clusters."""
+    ctx = C.context(name)
+    variant, params = C.CONTEXTS[name][1:]
+    n_sh = mesh.shape["data"]
+    tuples = pad_tuples(ctx.tuples, n_sh)
+    if variant == "prime":
+        miner, args = BatchMiner(ctx.sizes, **params), (tuples,)
+    else:
+        miner = NOACMiner(ctx.sizes, **params)
+        args = (tuples, pad_values(ctx.values, n_sh))
+    want = miner(*args)
+    dm = DistributedMiner(ctx.sizes, mesh, axes="data", strategy=strategy,
+                          **params)
+    _compare(dm(*args), want)
+    assert cluster_set(miner.materialise(want)) == C.oracle(name)
+
+
+# 18 + 16 + 4 entity bits and a 4-bit rank lane over D = 10 values:
+# a 42-bit two-word key (43 with the owner's validity bit)
+STARS = (2**18 - 1, 2**16 - 1, 10)
+# a float lane: owners search the validity-extended words globally
+# (63 live bits), or, at exactly 64, inside each segment
+FLOAT_SIZES = {63: (2**11, 2**11, 2**9), 64: (2**11, 2**11, 2**10)}
+
+MESHES = {
+    "mesh8": lambda: make_mesh((8,), ("data",)),
+    "mesh2x4": lambda: make_mesh((2, 4), ("pod", "data")),
+    "mesh4": lambda: jax.sharding.Mesh(
+        np.asarray(jax.devices()[:4]), ("data",),
+        axis_types=(jax.sharding.AxisType.Auto,)),
+}
+POD_DATA = ("pod", "data")
+
+#: (name, check, mesh, arguments after the mesh) — one verdict each
+CHECKS = [c for strategy in ("replicate", "shuffle") for c in [
+    (f"prime-mesh8-{strategy}", check, "mesh8",
+     ("data", strategy, (9, 7, 5), 160, 0.0), {"seed": 0}),
+    (f"prime-4ary-theta-mesh8-{strategy}", check, "mesh8",
+     ("data", strategy, (6, 6, 6, 4), 240, 0.3), {"seed": 1}),
+    (f"prime-mesh2x4-{strategy}", check, "mesh2x4",
+     (POD_DATA, strategy, (9, 7, 5), 160, 0.0), {"seed": 2}),
+    (f"noac-mesh8-{strategy}", check_noac, "mesh8",
+     ("data", strategy, (9, 7, 5), 160, 120.0, 0.0, 0), {"seed": 3}),
+    (f"noac-rho-minsup-mesh2x4-{strategy}", check_noac, "mesh2x4",
+     (POD_DATA, strategy, (7, 6, 5), 120, 80.0, 0.3, 2), {"seed": 4}),
+]] + [
+    ("owner-bounds-runs-delta1-shuffle", check_owner_bounds, "mesh4",
+     ("shuffle", STARS, C.HALF_STARS, 1.0), {"seed": 5, "path": "runs"}),
+    ("owner-bounds-runs-delta0-shuffle", check_owner_bounds, "mesh4",
+     ("shuffle", STARS, C.HALF_STARS, 0.0), {"seed": 5, "path": "runs"}),
+    ("owner-bounds-runs-delta1-replicate", check_owner_bounds, "mesh4",
+     ("replicate", STARS, C.HALF_STARS, 1.0), {"seed": 6, "path": "runs"}),
+] + [
+    (f"owner-bounds-float-{bits}bit-shuffle", check_owner_bounds, "mesh4",
+     ("shuffle", FLOAT_SIZES[bits], C.FLOATS, 0.5),
+     {"seed": seed, "path": "search", "prune_values": False})
+    for bits, seed in ((63, 7), (64, 8))
+] + [
+    (f"conformance-{name}-{strategy}", check_conformance, "mesh4",
+     (strategy, name), {})
+    for name in C.CONTEXTS for strategy in ("replicate", "shuffle")
+]
+
+CHECK_NAMES = [c[0] for c in CHECKS]
+
+
 def main():
-    mesh8 = make_mesh((8,), ("data",))
-    mesh2x4 = make_mesh((2, 4), ("pod", "data"))
-    for strategy in ("replicate", "shuffle"):
-        check(mesh8, "data", strategy, (9, 7, 5), 160, 0.0, seed=0)
-        check(mesh8, "data", strategy, (6, 6, 6, 4), 240, 0.3, seed=1)
-        check(mesh2x4, ("pod", "data"), strategy, (9, 7, 5), 160, 0.0, seed=2)
-        check_noac(mesh8, "data", strategy, (9, 7, 5), 160, 120.0, 0.0, 0,
-                   seed=3)
-        check_noac(mesh2x4, ("pod", "data"), strategy, (7, 6, 5), 120, 80.0,
-                   0.3, 2, seed=4)
-    mesh4 = jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("data",),
-                              axis_types=(jax.sharding.AxisType.Auto,))
-    # 18 + 16 + 4 entity bits and a 4-bit rank lane over D = 10 values:
-    # a 42-bit two-word key (43 with the owner's validity bit)
-    stars = (2**18 - 1, 2**16 - 1, 10)
-    plan = K.plan_context_keys(stars, True, len(HALF_STARS))[0]
-    assert (plan.total_bits, plan.words, plan.value_bits) == (42, 2, 4)
-    for delta in (1.0, 0.0):
-        check_owner_bounds(mesh4, "shuffle", stars, HALF_STARS, delta,
-                           seed=5, path="runs")
-    check_owner_bounds(mesh4, "replicate", stars, HALF_STARS, 1.0, seed=6,
-                       path="runs")
-    # a float lane: owners search the validity-extended words globally
-    # (63 live bits), or, at exactly 64, inside each segment
-    floats = np.linspace(-3.0, 3.0, 25, dtype=np.float32)
-    for sizes, bits, seed in (((2**11, 2**11, 2**9), 63, 7),
-                              ((2**11, 2**11, 2**10), 64, 8)):
+    stars = K.plan_context_keys(STARS, True, len(C.HALF_STARS))[0]
+    assert (stars.total_bits, stars.words, stars.value_bits) == (42, 2, 4)
+    for bits, sizes in FLOAT_SIZES.items():
         assert K.plan_context_keys(sizes, True)[0].total_bits == bits
-        check_owner_bounds(mesh4, "shuffle", sizes, floats, 0.5, seed=seed,
-                           path="search", prune_values=False)
+    meshes = {}
+    failed = 0
+    for name, fn, mesh, args, kw in CHECKS:
+        try:
+            if mesh not in meshes:
+                meshes[mesh] = MESHES[mesh]()
+            fn(meshes[mesh], *args, **kw)
+            verdict = {"check": name, "ok": True}
+        except Exception:                         # one verdict per check
+            failed += 1
+            verdict = {"check": name, "ok": False,
+                       "error": traceback.format_exc()}
+        print(json.dumps(verdict), flush=True)
+    if failed:
+        return 1
     print("OK")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    # before the first device query: the mesh needs 8 host devices
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    sys.exit(main())

@@ -1,17 +1,19 @@
 """Distributed (shard_map) engine: 1-device in-process parity + 8-device
-subprocess parity (real collectives on a forced host mesh)."""
+subprocess parity (real collectives on a forced host mesh), one case per
+check of the subprocess."""
+import json
 import os
 import subprocess
 import sys
 
 import numpy as np
+import jax
 import pytest
 
-import jax
-
+import _distributed_check as DC
 from repro.core import BatchMiner, DistributedMiner, pad_tuples
 from repro.data import synthetic
-from repro.launch.mesh import make_mesh
+from repro.launch.mesh import make_local_mesh, make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -32,16 +34,51 @@ def test_single_device_parity(strategy):
                                np.asarray(want.density), rtol=1e-6)
 
 
-def test_multidevice_subprocess():
-    """Real 8-device mesh (pod×data too) in a separate process so the main
-    test process keeps its single-device view."""
+@pytest.fixture(scope="module")
+def multidevice_run():
+    """One run of every check on a real 8-device mesh (pod×data too), in
+    a separate process so the main test process keeps its single-device
+    view: the process and its verdicts by check name."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tests", "_distributed_check.py")],
         capture_output=True, text=True, env=env, timeout=900)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "OK" in proc.stdout
+    verdicts = [json.loads(line) for line in proc.stdout.splitlines()
+                if line.startswith("{")]
+    return proc, {v["check"]: v for v in verdicts}
+
+
+@pytest.mark.parametrize("check", DC.CHECK_NAMES)
+def test_multidevice_subprocess(multidevice_run, check):
+    proc, verdicts = multidevice_run
+    assert check in verdicts, \
+        f"no verdict (rc {proc.returncode}):\n{proc.stderr[-4000:]}"
+    assert verdicts[check]["ok"], verdicts[check]["error"]
+
+
+def test_multidevice_verdict_per_check(monkeypatch, capsys):
+    """The subprocess body reports every check on a line of its own, a
+    failing one with its traceback, and goes on to the next."""
+    def fails(mesh, why):
+        raise AssertionError(why)
+    monkeypatch.setattr(DC, "MESHES", {"none": lambda: None})
+    monkeypatch.setattr(DC, "CHECKS", [
+        ("passes", lambda mesh: None, "none", (), {}),
+        ("fails", fails, "none", ("planted",), {}),
+        ("after", lambda mesh: None, "none", (), {})])
+    assert DC.main() == 1
+    lines = capsys.readouterr().out.splitlines()
+    verdicts = [json.loads(line) for line in lines]
+    assert [(v["check"], v["ok"]) for v in verdicts] == [
+        ("passes", True), ("fails", False), ("after", True)]
+    assert "AssertionError: planted" in verdicts[1]["error"]
+
+
+def test_local_mesh_spans_every_device():
+    mesh = make_local_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.devices.shape == (len(jax.devices()), 1)
 
 
 def test_padding_is_idempotent():

@@ -1,60 +1,53 @@
-"""CLI driver smoke tests: tricluster / train / serve mains."""
-import json
+"""CLI driver tests: the tricluster and cluster-serve mains and the
+compile-cache setting they share."""
+import contextlib
+import functools
+import io
 import os
+import re
 
 import pytest
 
+from repro.core import available_engines
 from repro.launch import cluster_serve as cs_mod
 from repro.launch import compile_cache as cc_mod
-from repro.launch import serve as serve_mod
-from repro.launch import train as train_mod
 from repro.launch import tricluster as tri_mod
 
 
-def test_tricluster_batch_imdb(capsys):
-    assert tri_mod.main(["--dataset", "imdb", "--backend", "batch",
-                         "--print-top", "1"]) == 0
-    out = capsys.readouterr().out
-    assert "unique clusters" in out
+#: The driver's flags of each variant on the IMDB-like context; NOAC
+#: filters by ρ_min, so that it keeps fewer clusters than prime does.
+VARIANT_ARGS = {"prime": [], "noac": ["--delta", "1", "--rho-min", "0.3"]}
 
 
-def test_tricluster_reference_and_noac(capsys):
-    assert tri_mod.main(["--dataset", "random", "--n-tuples", "256",
-                         "--backend", "reference"]) == 0
-    assert tri_mod.main(["--dataset", "frames", "--n-tuples", "512",
-                         "--delta", "100", "--rho-min", "0.5"]) == 0
-    out = capsys.readouterr().out
-    assert "NOAC" in out
+def _tricluster(*argv) -> str:
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        assert tri_mod.main(["--dataset", "imdb", *argv]) == 0
+    return out.getvalue()
 
 
-def test_tricluster_streaming(capsys):
-    assert tri_mod.main(["--dataset", "random", "--n-tuples", "512",
-                         "--backend", "streaming", "--chunks", "4"]) == 0
+def _kept(out: str, variant: str) -> int:
+    word = "triclusters" if variant == "noac" else "unique clusters"
+    return int(re.search(rf"(\d+) {word};", out).group(1))
 
 
-def test_train_driver_with_resume(tmp_path, capsys):
-    ckpt = str(tmp_path / "ckpt")
-    metrics = str(tmp_path / "m.json")
-    args = ["--arch", "h2o-danube-1.8b", "--smoke", "--steps", "6",
-            "--global-batch", "2", "--seq", "32", "--ckpt-dir", ckpt,
-            "--ckpt-every", "3", "--log-every", "2",
-            "--metrics-out", metrics]
-    assert train_mod.main(args) == 0
-    rows = json.load(open(metrics))
-    assert rows[-1]["step"] == 6
-    # resume two more steps from the checkpoint
-    args2 = [a if a != "6" else "8" for a in args] + ["--resume", "auto"]
-    assert train_mod.main(args2) == 0
-    out = capsys.readouterr().out
-    assert "resumed from step" in out
+@functools.lru_cache(None)
+def _reference_kept(variant: str) -> int:
+    return _kept(_tricluster("--backend", "reference", "--variant", variant,
+                             *VARIANT_ARGS[variant]), variant)
 
 
-def test_serve_driver(capsys):
-    assert serve_mod.main(["--arch", "qwen3-0.6b", "--smoke",
-                           "--batch", "2", "--prompt-len", "8",
-                           "--new-tokens", "4", "--max-len", "32"]) == 0
-    out = capsys.readouterr().out
-    assert "tok/s" in out
+@pytest.mark.parametrize("backend,variant", [
+    e for e in available_engines() if e[0] != "reference"])
+def test_tricluster_engine_matches_reference(backend, variant):
+    """Every registered (backend, variant) pair through the driver keeps
+    as many clusters on the IMDB-like context as ``--backend reference``,
+    and prints its top pattern where the engine materialises clusters."""
+    out = _tricluster("--backend", backend, "--variant", variant,
+                      "--print-top", "1", *VARIANT_ARGS[variant])
+    assert _kept(out, variant) == _reference_kept(variant) > 0
+    patterns = [ln for ln in out.splitlines() if ln.startswith("{")]
+    assert bool(patterns) == (backend != "distributed")
 
 
 @pytest.mark.parametrize("backend,rc", [("tpu", 2), ("cpu", 0)])

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.hlo import parse_module, profile_module
-from repro.analysis.roofline import model_flops
 
 
 _SYNTHETIC = """\
@@ -82,14 +81,3 @@ def test_live_scan_flops_counted_per_trip():
     raw = comp.cost_analysis()["flops"]
     assert prof.mxu_flops > 4 * raw   # XLA counted the body once
 
-
-def test_model_flops_shapes():
-    from repro.configs import SHAPES, get_config
-    cfg = get_config("qwen3-0.6b")
-    t = model_flops(cfg, SHAPES["train_4k"])
-    p = model_flops(cfg, SHAPES["prefill_32k"])
-    d = model_flops(cfg, SHAPES["decode_32k"])
-    n = cfg.n_active_params()
-    assert t == 6 * n * 4096 * 256
-    assert p == 2 * n * 32768 * 32
-    assert d == 2 * n * 128

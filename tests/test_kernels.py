@@ -9,105 +9,6 @@ from repro.kernels import ops, ref
 jax.config.update("jax_enable_x64", False)
 
 
-def _rand(key, shape, dtype):
-    x = jax.random.normal(key, shape, jnp.float32)
-    return x.astype(dtype)
-
-
-TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
-       jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("b,hq,hkv,sq,skv,d", [
-    (1, 4, 4, 128, 128, 64),      # MHA square
-    (2, 8, 2, 128, 256, 64),      # GQA, kv longer (prefill continuation)
-    (1, 4, 1, 64, 128, 128),      # MQA, sq not multiple of default bq
-    (1, 2, 2, 200, 200, 32),      # ragged: padding path
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_shapes(b, hq, hkv, sq, skv, d, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = _rand(ks[0], (b, hq, sq, d), dtype)
-    k = _rand(ks[1], (b, hkv, skv, d), dtype)
-    v = _rand(ks[2], (b, hkv, skv, d), dtype)
-    out = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), **TOL[dtype])
-
-
-@pytest.mark.parametrize("window", [32, 128, None])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_masks(window, causal):
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    b, h, s, d = 1, 2, 256, 64
-    q = _rand(ks[0], (b, h, s, d), jnp.float32)
-    k = _rand(ks[1], (b, h, s, d), jnp.float32)
-    v = _rand(ks[2], (b, h, s, d), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              bq=64, bk=64)
-    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_flash_attention_q_offset():
-    """Chunked prefill: q rows are a suffix of the kv range."""
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    b, h, d = 1, 2, 64
-    skv, sq = 256, 64
-    q = _rand(ks[0], (b, h, sq, d), jnp.float32)
-    k = _rand(ks[1], (b, h, skv, d), jnp.float32)
-    v = _rand(ks[2], (b, h, skv, d), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=True, q_offset=skv - sq,
-                              bq=64, bk=64)
-    want = ref.flash_attention_ref(q, k, v, causal=True, q_offset=skv - sq)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# decode attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("b,hq,hkv,s,d,kv_len,window", [
-    (2, 4, 2, 512, 64, 512, None),
-    (1, 8, 8, 1024, 64, 700, None),    # padded cache
-    (2, 4, 1, 512, 128, 512, 128),     # sliding window
-    (1, 2, 2, 300, 32, 300, None),     # ragged skv
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_attention(b, hq, hkv, s, d, kv_len, window, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = _rand(ks[0], (b, hq, d), dtype)
-    k = _rand(ks[1], (b, hkv, s, d), dtype)
-    v = _rand(ks[2], (b, hkv, s, d), dtype)
-    out = ops.decode_attention(q, k, v, kv_len=kv_len, window=window, bk=256)
-    want = ref.decode_attention_ref(q, k, v, kv_len=kv_len, window=window)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), **TOL[dtype])
-
-
-# ---------------------------------------------------------------------------
-# rmsnorm
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("shape", [(4, 64), (2, 3, 128), (256, 512), (5, 96)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rmsnorm(shape, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(4), 2)
-    x = _rand(ks[0], shape, dtype)
-    w = _rand(ks[1], shape[-1:], jnp.float32) + 1.0
-    out = ops.rmsnorm(x, w)
-    want = ref.rmsnorm_ref(x, w)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), **TOL[dtype])
-
-
 # ---------------------------------------------------------------------------
 # signature
 # ---------------------------------------------------------------------------
